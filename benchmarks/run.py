@@ -57,6 +57,8 @@ def main() -> None:
         os.environ["REPRO_BENCH_CI"] = "1"
     picks = set(args.only.split(","))
     from repro import obs
+    from repro.kernels.config import enable_compile_cache
+    enable_compile_cache()
     runlog_path = args.runlog
     if runlog_path is None and args.json:
         runlog_path = os.path.splitext(args.json)[0] + ".runlog.jsonl"

@@ -128,6 +128,12 @@ def _options_from_args(args):
                                    kernel_interpret=interp)
 
 
+def _device(options) -> dict:
+    """The run log manifest's ``device`` field for this engine."""
+    from .kernels.config import device_summary
+    return device_summary(options.engine, options.kernel_interpret)
+
+
 def _obs_paths(args) -> tuple:
     """Resolve the run-log path and live-export prefix.
 
@@ -197,7 +203,7 @@ def cmd_mem(args, argv) -> int:
             runlog = obs.RunLog(runlog_path)
             runlog.manifest("repro.cli mem", argv=argv,
                             engine=options.engine, options=options,
-                            index=aligner.index,
+                            index=aligner.index, device=_device(options),
                             shard=f"{shard[0]}/{shard[1]}",
                             reads1=args.reads1, reads2=args.reads2,
                             interleaved=args.interleaved,
@@ -278,8 +284,9 @@ def cmd_memdist(args, argv) -> int:
         runlog = obs.RunLog(args.runlog)
         runlog.manifest("repro.cli memdist", argv=argv,
                         engine=options.engine, options=options,
-                        index=aligner.index, reads1=args.reads1,
-                        reads2=args.reads2, interleaved=args.interleaved,
+                        index=aligner.index, device=_device(options),
+                        reads1=args.reads1, reads2=args.reads2,
+                        interleaved=args.interleaved,
                         workers=args.workers, chunk_bases=args.chunk_bases,
                         workdir=str(workdir))
         _log(f"run {runlog.run_id}: logging events to {args.runlog}")
@@ -350,7 +357,8 @@ def cmd_serve(args, argv) -> int:
         from . import obs
         runlog = obs.RunLog(args.runlog)
         runlog.manifest("repro.cli serve", argv=argv,
-                        engine=options.engine, options=options, index=index)
+                        engine=options.engine, options=options, index=index,
+                        device=_device(options))
         _log(f"run {runlog.run_id}: logging events to {args.runlog}")
     if args.live not in (None, "off", "-"):
         from . import obs
@@ -637,8 +645,10 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
+    from .kernels.config import enable_compile_cache
     argv = list(sys.argv[1:] if argv is None else argv)
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     return args.fn(args, argv)
 
 

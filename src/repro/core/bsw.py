@@ -192,27 +192,36 @@ def _prefix_max(x, axis_len):
 
 def bsw_init_state(qlens, h0s, oe_ins, e_ins, qmax: int):
     """First-row fill: eh_h[0]=h0; eh_h[j>=1]=relu(h0-oe_ins-(j-1)e_ins)
-    (values that would be <= 0 stay 0, matching the scalar early-exit)."""
+    (values that would be <= 0 stay 0, matching the scalar early-exit).
+
+    ``qlens``/``h0s`` are (W, 1) columns, and every per-lane state entry
+    stays a (W, 1) column: the TPU kernel cannot reshape a 1-D lane vector
+    into the 2-D layout the row arrays broadcast against."""
     W = qlens.shape[0]
-    jj = jnp.arange(qmax + 1, dtype=I32)
-    fill = h0s[:, None] - oe_ins - (jj[None, :] - 1) * e_ins
-    eh_h0 = jnp.where(jj[None, :] == 0, h0s[:, None],
-                      jnp.maximum(fill, 0)).astype(I32)
-    eh_h0 = jnp.where(jj[None, :] <= qlens[:, None], eh_h0, 0)
+    jj = jax.lax.broadcasted_iota(I32, (1, qmax + 1), 1)
+    fill = h0s - oe_ins - (jj - 1) * e_ins
+    eh_h0 = jnp.where(jj == 0, h0s, jnp.maximum(fill, 0)).astype(I32)
+    eh_h0 = jnp.where(jj <= qlens, eh_h0, 0)
     eh_e0 = jnp.zeros((W, qmax + 1), I32)
+    col = lambda v: jnp.full((W, 1), v, I32)
     return (eh_h0, eh_e0,
-            jnp.zeros(W, I32), qlens.astype(I32),          # beg, end
+            col(0), qlens.astype(I32),                     # beg, end
             h0s.astype(I32),                               # max
-            jnp.full(W, -1, I32), jnp.full(W, -1, I32),    # max_i, max_j
-            jnp.full(W, -1, I32), jnp.full(W, -1, I32),    # max_ie, gscore
-            jnp.zeros(W, I32),                             # max_off
-            jnp.ones(W, bool))                             # alive
+            col(-1), col(-1),                              # max_i, max_j
+            col(-1), col(-1),                              # max_ie, gscore
+            col(0),                                        # max_off
+            col(1))                                        # alive (0/1)
 
 
-def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
+def bsw_row_step(i, st, qs, trow, qlens, tlens, h0s, ws,
                  a, b, o_del, e_del, o_ins, e_ins, zdrop, qmax: int):
     """One DP row for all W lanes — shared by the jnp batch wrapper and the
-    Pallas kernel (both must stay bit-identical to the scalar oracle)."""
+    Pallas kernel (both must stay bit-identical to the scalar oracle).
+
+    qs (W,qmax) codes; trow (W, 1) is the target base of row i (each
+    caller reads it its own way: the TPU kernel has no dynamic lane
+    slice); qlens/tlens/h0s/ws and the per-lane state are (W, 1) columns
+    (see ``bsw_init_state``)."""
     (eh_h_st, eh_e_st, beg_st, end_st, max_st, max_i_st, max_j_st,
      max_ie_st, gscore_st, max_off_st, alive_st) = st
     W = qs.shape[0]
@@ -221,14 +230,13 @@ def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
     jj = jax.lax.broadcasted_iota(I32, (1, qmax + 1), 1)   # eh index
     jq = jax.lax.broadcasted_iota(I32, (1, qmax), 1)       # query index
 
-    act = alive_st & (i < tlens)
+    act = (alive_st != 0) & (i < tlens)
     beg = jnp.maximum(beg_st, i - ws)
     end = jnp.minimum(jnp.minimum(end_st, i + ws + 1), qlens)
     h_first = jnp.where(beg == 0,
                         jnp.maximum(h0s - (o_del + e_del * (i + 1)), 0), 0)
-    trow = jax.lax.dynamic_slice_in_dim(ts, i, 1, axis=1)[:, 0]   # (W,)
-    srow = _score_arith(trow[:, None], qs, a, b)            # (W,qmax)
-    in_band = (jq >= beg[:, None]) & (jq < end[:, None])
+    srow = _score_arith(trow, qs, a, b)                     # (W,qmax)
+    in_band = (jq >= beg) & (jq < end)
     Hd = eh_h_st[:, :qmax]                                  # H(i-1, j-1)
     Ec = eh_e_st[:, :qmax]                                  # E(i, j)
     Mq = jnp.where(Hd != 0, Hd + srow, 0)
@@ -240,16 +248,16 @@ def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
     cmax = _prefix_max(g, qmax)
     cmax_excl = jnp.concatenate(
         [jnp.full((W, 1), NEG, I32), cmax[:, :-1]], axis=1)
-    F = jnp.maximum(cmax_excl, beg[:, None] * e_ins) - jq * e_ins
+    F = jnp.maximum(cmax_excl, beg * e_ins) - jq * e_ins
     H = jnp.maximum(jnp.maximum(Mq, Ec_b), F)
     H = jnp.where(in_band, H, 0)
     # row max, LAST index attaining it (scalar tie-break)
-    m = jnp.max(H, axis=1)
-    is_max = (H == m[:, None]) & in_band
-    mj = jnp.max(jnp.where(is_max, jq, -1), axis=1)
+    m = jnp.max(H, axis=1, keepdims=True)
+    is_max = (H == m) & in_band
+    mj = jnp.max(jnp.where(is_max, jq, -1), axis=1, keepdims=True)
     mj = jnp.where(m > 0, mj, -1)
     # h1_final = H(i, end-1) (or first-col value if band empty)
-    h_end = jnp.max(jnp.where(jq == (end - 1)[:, None], H, NEG), axis=1)
+    h_end = jnp.max(jnp.where(jq == end - 1, H, NEG), axis=1, keepdims=True)
     h1_final = jnp.where(end > beg, h_end, h_first)
     # E(i+1, j) and new stored arrays
     t_del = jnp.maximum(Mq - oe_del, 0)
@@ -258,13 +266,13 @@ def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
     # h_first (beg==0) or 0; end gets H(i, end-1).
     Hshift = jnp.concatenate(
         [jnp.zeros((W, 1), I32), H], axis=1)                # H(i, j-1) at j
-    wr = (jj >= beg[:, None]) & (jj <= end[:, None])
-    newh = jnp.where(jj == beg[:, None], h_first[:, None], Hshift)
-    newh = jnp.where(jj == end[:, None], h1_final[:, None], newh)
-    eh_h = jnp.where(wr & act[:, None], newh, eh_h_st)
+    wr = (jj >= beg) & (jj <= end) & act
+    newh = jnp.where(jj == beg, h_first, Hshift)
+    newh = jnp.where(jj == end, h1_final, newh)
+    eh_h = jnp.where(wr, newh, eh_h_st)
     Eword = jnp.concatenate([E_next, jnp.zeros((W, 1), I32)], axis=1)
-    newe = jnp.where(jj == end[:, None], 0, Eword)
-    eh_e = jnp.where(wr & act[:, None], newe, eh_e_st)
+    newe = jnp.where(jj == end, 0, Eword)
+    eh_e = jnp.where(wr, newe, eh_e_st)
     # gscore bookkeeping (before the m==0 break, as in scalar code)
     at_end = act & (end == qlens)
     upd_g = at_end & ~(gscore_st > h1_final)
@@ -288,11 +296,11 @@ def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
     zbreak = cont & ~better & (zdrop > 0) & (zd > zdrop)
     # band update (only lanes continuing past this row)
     nz = (eh_h != 0) | (eh_e != 0)
-    cand = nz & (jj >= beg[:, None]) & (jj < end[:, None])
-    beg_n = jnp.min(jnp.where(cand, jj, qmax + 1), axis=1)
+    cand = nz & (jj >= beg) & (jj < end)
+    beg_n = jnp.min(jnp.where(cand, jj, qmax + 1), axis=1, keepdims=True)
     beg_n = jnp.minimum(beg_n, end)
-    cand2 = nz & (jj >= beg_n[:, None]) & (jj <= end[:, None])
-    jstar = jnp.max(jnp.where(cand2, jj, beg_n[:, None] - 1), axis=1)
+    cand2 = nz & (jj >= beg_n) & (jj <= end)
+    jstar = jnp.max(jnp.where(cand2, jj, beg_n - 1), axis=1, keepdims=True)
     end_n = jnp.minimum(jstar + 2, qlens)
     keep = cont & ~zbreak
     return (eh_h, eh_e,
@@ -303,7 +311,15 @@ def bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
             jnp.where(cont, max_j, max_j_st),
             max_ie, gscore,
             jnp.where(cont, max_off, max_off_st),
-            alive_st & keep)
+            keep.astype(I32))                       # act implies alive
+
+
+def bsw_result(st):
+    """Final state -> (W, 6) int32 columns: score, qle, tle, gtle, gscore,
+    max_off (ExtResult field order)."""
+    (_, _, _, _, max_, max_i, max_j, max_ie, gscore, max_off, _) = st
+    return jnp.concatenate([max_, max_j + 1, max_i + 1,
+                            max_ie + 1, gscore, max_off], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("qmax", "tmax"))
@@ -314,16 +330,15 @@ def _bsw_batch_jit(qs, ts, qlens, tlens, h0s, ws, a, b, o_del, e_del,
     qs (W,qmax) int32 codes (pad=4), ts (W,tmax) int32, qlens/tlens/h0s/ws
     (W,) int32.  Returns stacked (score qle tle gtle gscore max_off) (6,W).
     """
+    qlens, tlens, h0s, ws = (v[:, None] for v in (qlens, tlens, h0s, ws))
     state = bsw_init_state(qlens, h0s, o_ins + e_ins, e_ins, qmax)
 
     def row(i, st):
-        return bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
+        trow = jax.lax.dynamic_slice_in_dim(ts, i, 1, axis=1)
+        return bsw_row_step(i, st, qs, trow, qlens, tlens, h0s, ws,
                             a, b, o_del, e_del, o_ins, e_ins, zdrop, qmax)
 
-    st = jax.lax.fori_loop(0, tmax, row, state)
-    (_, _, _, _, max_, max_i, max_j, max_ie, gscore, max_off, _) = st
-    return jnp.stack([max_, max_j + 1, max_i + 1,
-                      max_ie + 1, gscore, max_off])
+    return bsw_result(jax.lax.fori_loop(0, tmax, row, state)).T
 
 
 def bsw_extend_batch(queries: list[np.ndarray], targets: list[np.ndarray],

@@ -40,6 +40,7 @@ import threading
 from typing import NamedTuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 # Base codes. 0=A 1=C 2=G 3=T; 4 = sentinel marker in BWT bytes; 5 = pad.
@@ -61,8 +62,13 @@ def revcomp(codes: np.ndarray) -> np.ndarray:
     return (3 - codes[::-1]).astype(codes.dtype)
 
 
-def suffix_array(s: np.ndarray) -> np.ndarray:
-    """Suffix array by prefix doubling (O(n log^2 n), numpy lexsort rounds).
+def suffix_array(s: np.ndarray, *, packed: bool | None = None) -> np.ndarray:
+    """Suffix array by prefix doubling (O(n log^2 n), one numpy sort per
+    round).  The rank pair (rank[i], rank[i+k]) is packed into one int64
+    key, which sorts about 5x faster than a two-key lexsort, while
+    (n+1)^2 fits an int64 (n < 3.03e9, whole human chromosomes); longer
+    sequences (a whole-genome forward+reverse sequence) sort the pair
+    with a lexsort.  ``packed`` forces either sort (tests).
 
     The caller passes the sequence WITHOUT sentinel; we treat the virtual
     sentinel as smaller than everything by ranking positions past the end
@@ -70,6 +76,8 @@ def suffix_array(s: np.ndarray) -> np.ndarray:
     """
     s = np.asarray(s, dtype=np.int64)
     n = len(s) + 1  # +1 for the virtual sentinel position at index len(s)
+    if packed is None:
+        packed = (n + 1) ** 2 < 2 ** 63
     rank = np.full(n, -1, dtype=np.int64)
     rank[:-1] = s
     k = 1
@@ -77,9 +85,16 @@ def suffix_array(s: np.ndarray) -> np.ndarray:
         key2 = np.full(n, -1, dtype=np.int64)
         if k < n:
             key2[: n - k] = rank[k:]
-        sa = np.lexsort((key2, rank))
+        if packed:
+            key = (rank + 1) * (n + 1) + (key2 + 1)    # ranks are in [-1, n)
+            sa = np.argsort(key)    # ties reorder freely: the last round has none
+            key = key[sa]
+            diff = key[1:] != key[:-1]
+        else:
+            sa = np.lexsort((key2, rank))
+            diff = ((rank[sa[1:]] != rank[sa[:-1]])
+                    | (key2[sa[1:]] != key2[sa[:-1]]))
         new = np.empty(n, dtype=np.int64)
-        diff = (rank[sa[1:]] != rank[sa[:-1]]) | (key2[sa[1:]] != key2[sa[:-1]])
         new[sa] = np.concatenate(([0], np.cumsum(diff)))
         rank = new
         if rank[sa[-1]] == n - 1:
@@ -120,7 +135,7 @@ class FMIndex:
     occ128_packed: np.ndarray
     sa_sampled: np.ndarray
     _occ_prefix: np.ndarray | None = None
-    _device: FMArrays | None = None
+    _device: dict = dataclasses.field(default_factory=dict)  # dev -> view
 
     # ---------------- host-side scalar occ (oracle) ----------------
     def occ(self, c: int, i: int) -> int:
@@ -178,15 +193,21 @@ class FMIndex:
         return ((int(self.sa_sampled[j // SA_SAMPLE]) + t) % self.N, t)
 
     def device(self) -> FMArrays:
-        if self._device is not None:
-            return self._device
-        # one lock for all indexes: the build is rare (once per index)
-        # and concurrent aligner calls sharing one index (repro.serve)
-        # must not duplicate the host->device transfer
+        """Device view on this thread's default device: a run that gives
+        each worker thread its own chip (``jax.default_device``, as
+        ``repro.dist.run`` does) gets one copy of the index per chip."""
+        dev = jax.config.jax_default_device or jax.devices()[0]
+        view = self._device.get(dev)
+        if view is not None:
+            return view
+        # one lock for all indexes: the build is rare (once per index and
+        # device) and concurrent aligner calls sharing one index
+        # (repro.serve) must not duplicate the host->device transfer
         with _DEVICE_LOCK:
-            if self._device is not None:
-                return self._device
-            self._device = FMArrays(
+            view = self._device.get(dev)
+            if view is not None:
+                return view
+            view = self._device[dev] = FMArrays(
                 occ32_counts=jnp.asarray(self.occ32_counts, dtype=I32),
                 occ32_bytes=jnp.asarray(self.occ32_bytes),
                 occ128_counts=jnp.asarray(self.occ128_counts, dtype=I32),
@@ -199,7 +220,7 @@ class FMIndex:
                 n_ref=jnp.asarray(self.n_ref, dtype=I32),
                 N=jnp.asarray(self.N, dtype=I32),
             )
-        return self._device
+        return view
 
 
 # Fields persisted by the on-disk index bundle (repro.io.store); the occ

@@ -32,7 +32,10 @@ per-worker streaming into a fault-tolerant job:
    ``ft.straggler.StragglerMonitor`` fed per-chunk wall times can demand
    a mid-shard requeue (``action == "checkpoint"``): the shard
    checkpoints and re-enters the retry path with ``reason="straggler"``.
-5. **Deterministic merge.**  The header (from the one shared ``Aligner``;
+5. **One chip per shard.**  Shard ``i`` runs on ``jax.devices()[i % n]``
+   (its worker thread's ``jax.default_device``), so on a four-chip host
+   each shard holds its own index copy and dispatches its own kernels.
+6. **Deterministic merge.**  The header (from the one shared ``Aligner``;
    ``@PG`` records the plan) plus the per-shard bodies concatenated in
    shard order, written atomically — byte-identical to an unsharded
    ``repro.cli mem`` run with the same ``-K`` (tested, CI-asserted).
@@ -56,6 +59,7 @@ import pathlib
 import threading
 import time
 
+import jax
 import numpy as np
 
 from .. import obs
@@ -279,7 +283,8 @@ def _run_shard(aligner, plan: JobPlan, sp: ShardPlan,
             runlog.emit("shard_start", shard=sp.shard,
                         chunk_start=sp.start, chunk_stop=sp.stop,
                         resumed=resumed, chunks_done=done,
-                        sam_offset=offset)
+                        sam_offset=offset,
+                        device=str(jax.config.jax_default_device))
         t0 = time.perf_counter()
         batches = open_batches(plan.reads1, plan.reads2,
                                interleaved=plan.interleaved,
@@ -414,10 +419,15 @@ def run_job(aligner, reads1, reads2=None, out=None, *,
     summaries: dict[int, dict] = {}
     n_retries = 0
 
+    devices = jax.devices()
+
     def attempt(sp: ShardPlan) -> dict:
-        return _run_shard(aligner, plan, sp, workdir, runlog=runlog,
-                          inject=inject, monitor=monitor,
-                          monitor_lock=monitor_lock, engine=engine)
+        # one chip per shard (round-robin): each worker thread places its
+        # index copy and kernel inputs on its own device
+        with jax.default_device(devices[sp.shard % len(devices)]):
+            return _run_shard(aligner, plan, sp, workdir, runlog=runlog,
+                              inject=inject, monitor=monitor,
+                              monitor_lock=monitor_lock, engine=engine)
 
     with ThreadPoolExecutor(max_workers=len(shard_plans)) as pool:
         pending = {pool.submit(attempt, sp): sp for sp in shard_plans}

@@ -13,7 +13,8 @@ way bwa hangs ``.bwt``/``.sa``/``.ann`` off the FASTA path.
                    | null                 # null = plain single-seq FMIndex
       }
 
-* ``{prefix}.ri.npz`` — the numpy arrays (``np.savez_compressed``), one
+* ``{prefix}.ri.npz`` — the numpy arrays (``np.savez``, uncompressed as
+  bwa's own index files are: zlib took minutes at chromosome scale), one
   entry per name in ``core.fmindex.PERSIST_ARRAYS``: the packed sequence
   ``seq``, the UNCOMPRESSED suffix array ``sa`` (paper §4.5) plus the
   value-sampled ``sa_sampled``, the BWT bytes, cumulative counts ``C``
@@ -73,7 +74,7 @@ def save_index(prefix, idx: FMIndex) -> tuple[pathlib.Path, pathlib.Path]:
         **{k: int(getattr(idx, k)) for k in PERSIST_SCALARS},
         "contigs": contig_table(idx),
     }
-    np.savez_compressed(npzp, **{k: getattr(idx, k) for k in PERSIST_ARRAYS})
+    np.savez(npzp, **{k: getattr(idx, k) for k in PERSIST_ARRAYS})
     with open(jp, "w") as f:
         json.dump(meta, f, indent=1)
         f.write("\n")

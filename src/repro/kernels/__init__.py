@@ -9,5 +9,7 @@
 from .config import (  # noqa: F401
     COMPILED_BACKENDS,
     default_interpret,
+    device_summary,
+    enable_compile_cache,
     resolve_interpret,
 )

@@ -2,11 +2,18 @@
 
 TPU mapping of the paper's AVX512 inter-task vectorization:
 
-* the task axis is the VPU **lane** dimension — one grid cell processes a
-  block of LANES=128 sequence pairs (AVX512 gives 64 8-bit lanes; a TPU
-  VREG row gives 128 32-bit lanes);
+* one grid cell processes a block of LANES=128 sequence pairs, the
+  paper's task lanes (AVX512 gives 64 8-bit lanes).  On the TPU each task
+  is one sublane row of the (8, 128) VREG tiles and the query columns run
+  along the 128 hardware lanes, so one DP row of a block is a stack of
+  whole tiles;
 * sequences arrive SoA (``(LANES, qmax)`` / ``(LANES, tmax)``) so each DP
   row touches contiguous VMEM — the paper's AoS->SoA conversion (§5.3.3);
+* per-task scalars (lengths, h0, band, the running max/band state) are
+  ``(LANES, 1)`` columns that broadcast along the lanes; the target base
+  of row i is a one-hot masked reduction over ``ts`` (the kernel has no
+  dynamic lane slice), and the loop-carried ``alive`` flag is int32
+  (Mosaic cannot carry boolean vectors through the row loop);
 * both DP rows (H and E) live in VMEM scratch for the whole row loop: the
   working set per block is LANES x (qmax+1) x 2 x 4B ≈ 0.5 MB at qmax=512,
   far under the ~16 MB VMEM budget, so BlockSpec keeps everything resident;
@@ -28,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.bsw import bsw_init_state, bsw_row_step
+from repro.core.bsw import bsw_init_state, bsw_result, bsw_row_step
 
 LANES = 128
 
@@ -38,21 +45,21 @@ def _bsw_kernel_body(qs_ref, ts_ref, qlens_ref, tlens_ref, h0s_ref, ws_ref,
                      qmax, tmax):
     qs = qs_ref[...]
     ts = ts_ref[...]
-    qlens = qlens_ref[...]
+    qlens = qlens_ref[...]                            # (LANES, 1) columns
     tlens = tlens_ref[...]
     h0s = h0s_ref[...]
     ws = ws_ref[...]
 
     state = bsw_init_state(qlens, h0s, o_ins + e_ins, e_ins, qmax)
+    jt = jax.lax.broadcasted_iota(jnp.int32, (1, tmax), 1)
 
     def row(i, st):
-        return bsw_row_step(i, st, qs, ts, qlens, tlens, h0s, ws,
+        # target base of row i: a one-hot reduction (no dynamic lane slice)
+        trow = jnp.sum(jnp.where(jt == i, ts, 0), axis=1, keepdims=True)
+        return bsw_row_step(i, st, qs, trow, qlens, tlens, h0s, ws,
                             a, b, o_del, e_del, o_ins, e_ins, zdrop, qmax)
 
-    st = jax.lax.fori_loop(0, tmax, row, state)
-    (_, _, _, _, max_, max_i, max_j, max_ie, gscore, max_off, _) = st
-    out_ref[...] = jnp.stack([max_, max_j + 1, max_i + 1,
-                              max_ie + 1, gscore, max_off])
+    out_ref[...] = bsw_result(jax.lax.fori_loop(0, tmax, row, state))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -60,9 +67,10 @@ def _bsw_kernel_body(qs_ref, ts_ref, qlens_ref, tlens_ref, h0s_ref, ws_ref,
     "interpret"))
 def bsw_pallas_call(qs, ts, qlens, tlens, h0s, ws, *, a, b, o_del, e_del,
                     o_ins, e_ins, zdrop, qmax, tmax, interpret=True):
-    """qs (W,qmax) / ts (W,tmax) int32 (pad code 4); W % LANES == 0.
+    """qs (W,qmax) / ts (W,tmax) int32 (pad code 4); qlens/tlens/h0s/ws
+    (W, 1) int32 columns; W % LANES == 0.
 
-    Returns (6, W) int32: score, qle, tle, gtle, gscore, max_off.
+    Returns (W, 6) int32: score, qle, tle, gtle, gscore, max_off.
     """
     W = qs.shape[0]
     assert W % LANES == 0, "pad the task batch to a multiple of LANES"
@@ -70,18 +78,16 @@ def bsw_pallas_call(qs, ts, qlens, tlens, h0s, ws, *, a, b, o_del, e_del,
     body = functools.partial(
         _bsw_kernel_body, a=a, b=b, o_del=o_del, e_del=e_del, o_ins=o_ins,
         e_ins=e_ins, zdrop=zdrop, qmax=qmax, tmax=tmax)
+    col = pl.BlockSpec((LANES, 1), lambda g: (g, 0))
     return pl.pallas_call(
         body,
         grid=grid,
         in_specs=[
             pl.BlockSpec((LANES, qmax), lambda g: (g, 0)),
             pl.BlockSpec((LANES, tmax), lambda g: (g, 0)),
-            pl.BlockSpec((LANES,), lambda g: (g,)),
-            pl.BlockSpec((LANES,), lambda g: (g,)),
-            pl.BlockSpec((LANES,), lambda g: (g,)),
-            pl.BlockSpec((LANES,), lambda g: (g,)),
+            col, col, col, col,
         ],
-        out_specs=pl.BlockSpec((6, LANES), lambda g: (0, g)),
-        out_shape=jax.ShapeDtypeStruct((6, W), jnp.int32),
+        out_specs=pl.BlockSpec((LANES, 6), lambda g: (g, 0)),
+        out_shape=jax.ShapeDtypeStruct((W, 6), jnp.int32),
         interpret=interpret,
     )(qs, ts, qlens, tlens, h0s, ws)

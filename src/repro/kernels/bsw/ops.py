@@ -55,11 +55,12 @@ def _bsw_extend_pallas(queries, targets, h0s, p, ws, qmax, tmax, interpret):
     for i in range(W):
         ws_in[i] = adjusted_band(int(qlens[i]), p,
                                  p.w if ws is None else int(ws[i]))
+    col = lambda v: jnp.asarray(v[:, None])
     out = bsw_pallas_call(
-        jnp.asarray(qs), jnp.asarray(ts), jnp.asarray(ql_in),
-        jnp.asarray(tl_in), jnp.asarray(h0_in), jnp.asarray(ws_in),
+        jnp.asarray(qs), jnp.asarray(ts), col(ql_in), col(tl_in),
+        col(h0_in), col(ws_in),
         a=p.a, b=p.b, o_del=p.o_del, e_del=p.e_del, o_ins=p.o_ins,
         e_ins=p.e_ins, zdrop=p.zdrop, qmax=qmax, tmax=tmax,
         interpret=interpret)
     out = np.asarray(out)
-    return [ExtResult(*(int(v) for v in out[:, i])) for i in range(W)]
+    return [ExtResult(*(int(v) for v in out[i])) for i in range(W)]

@@ -40,12 +40,12 @@ BASE_ETA = 128    # baseline bucket width (2-bit packed)
 
 def _occ_kernel_body(bytes_ref, c_ref, r_ref, base_ref, out_ref, *, qb):
     rows = bytes_ref[...].astype(jnp.int32)          # (qb, 32)
-    c = c_ref[...]                                   # (qb,)
-    r = r_ref[...]                                   # (qb,)
-    base = base_ref[...]                             # (qb,)
+    c = c_ref[...]                                   # (qb, 1)
+    r = r_ref[...]                                   # (qb, 1)
+    base = base_ref[...]                             # (qb, 1)
     lane = jax.lax.broadcasted_iota(jnp.int32, (qb, ETA), 1)
-    m = (rows == c[:, None]) & (lane < r[:, None])
-    out_ref[...] = base + jnp.sum(m.astype(jnp.int32), axis=1)
+    m = (rows == c) & (lane < r)
+    out_ref[...] = base + jnp.sum(m.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def _occ_packed_kernel_body(packed_ref, c_ref, r_ref, base_ref, out_ref, *,
@@ -59,32 +59,30 @@ def _occ_packed_kernel_body(packed_ref, c_ref, r_ref, base_ref, out_ref, *,
     codes = (packed[:, :, None] >> shifts) & 3       # (qb, 32, 4)
     codes = codes.reshape(qb, BASE_ETA)
     lane = jax.lax.broadcasted_iota(jnp.int32, (qb, BASE_ETA), 1)
-    m = (codes == c[:, None]) & (lane < r[:, None])
-    out_ref[...] = base + jnp.sum(m.astype(jnp.int32), axis=1)
+    m = (codes == c) & (lane < r)
+    out_ref[...] = base + jnp.sum(m.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def _occ_call(body, width, bucket_rows, c, r, base, *, qb, interpret):
+    # per-query operands are (T, 1) columns: a 1-D (qb,) block does not
+    # match the tiling XLA gives a 1-D int32 array on TPU
     T = bucket_rows.shape[0]
     assert T % qb == 0
     grid = (T // qb,)
+    col = pl.BlockSpec((qb, 1), lambda g: (g, 0))
     return pl.pallas_call(
         functools.partial(body, qb=qb),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((qb, width), lambda g: (g, 0)),
-            pl.BlockSpec((qb,), lambda g: (g,)),
-            pl.BlockSpec((qb,), lambda g: (g,)),
-            pl.BlockSpec((qb,), lambda g: (g,)),
-        ],
-        out_specs=pl.BlockSpec((qb,), lambda g: (g,)),
-        out_shape=jax.ShapeDtypeStruct((T,), jnp.int32),
+        in_specs=[pl.BlockSpec((qb, width), lambda g: (g, 0)), col, col, col],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((T, 1), jnp.int32),
         interpret=interpret,
     )(bucket_rows, c, r, base)
 
 
 @functools.partial(jax.jit, static_argnames=("qb", "interpret"))
 def occ_count_pallas_call(bucket_bytes, c, r, base, *, qb=QB, interpret=True):
-    """bucket_bytes (T,32) uint8, c/r/base (T,) int32 -> occ values (T,).
+    """bucket_bytes (T,32) uint8, c/r/base (T,1) int32 -> occ values (T,1).
 
     T must be a multiple of ``qb`` (ops.py pads).
     """

@@ -75,9 +75,10 @@ def _occ_impl(fm: FMArrays, c: jnp.ndarray, i: jnp.ndarray, *,
     Tp = -(-T // qb) * qb
     pad = Tp - T
     rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    out = call(rows, jnp.pad(cf, (0, pad)), jnp.pad(r, (0, pad)),
-               jnp.pad(base, (0, pad)), qb=qb, interpret=interpret)
-    return out[:T].reshape(shape)
+    column = lambda v: jnp.pad(v, (0, pad)).reshape(Tp, 1)
+    out = call(rows, column(cf), column(r), column(base), qb=qb,
+               interpret=interpret)
+    return out[:T, 0].reshape(shape)
 
 
 _occ_pallas_jit = jax.jit(_occ_impl,

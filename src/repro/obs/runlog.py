@@ -20,7 +20,8 @@ per-batch rates survive clock steps.  Well-known events:
 
 * ``run_start``   — the manifest: tool, argv, pid/host/python, engine,
   the full flattened ``AlignOptions``, the index fingerprint
-  (``index_fingerprint``), shard identity;
+  (``index_fingerprint``), the device platform/kind/count and (for the
+  ``pallas`` engine) the resolved kernel mode, shard identity;
 * ``batch``       — per-batch progress: batch ordinal, sizes, cumulative
   reads/records, instantaneous + cumulative reads/s, ETA when a total
   is known;
@@ -153,7 +154,8 @@ class RunLog:
                  options=None, index=None, **fields) -> dict | None:
         """The ``run_start`` event: everything needed to reproduce the
         invocation (options are the flattened AlignOptions dict, index
-        is an ``index_fingerprint``)."""
+        is an ``index_fingerprint``; callers that resolve an engine pass
+        ``device=kernels.device_summary(...)`` among the fields)."""
         if index is not None and not isinstance(index, dict):
             index = index_fingerprint(index)
         return self.emit(

@@ -1,11 +1,7 @@
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # degrade gracefully: property tests skip
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.core.bsw import (BSWParams, bsw_extend, bsw_extend_batch,
                             sort_tasks_by_length, wasted_cell_stats)
@@ -47,31 +43,26 @@ def test_batch_bit_identical_to_oracle(cfg):
     assert exp == got
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=30, deadline=None)
-    @given(st.integers(0, 2**31 - 1), st.integers(1, 80), st.integers(1, 80),
-           st.integers(1, 60))
-    def test_property_single_pair(seed, ql, tl, h0):
-        """Invariants: score >= h0 is NOT guaranteed (zdrop), but score >=
-        the best row max seen; qle/tle within bounds; gscore <= score +
-        clip room."""
-        rng = np.random.default_rng(seed)
-        q = rng.integers(0, 4, size=ql).astype(np.uint8)
-        t = rng.integers(0, 4, size=tl).astype(np.uint8)
-        p = BSWParams()
-        r = bsw_extend(q, t, h0, p)
-        assert 0 <= r.qle <= ql
-        assert 0 <= r.tle <= tl
-        assert 0 <= r.gtle <= tl
-        assert r.score >= h0        # max_ starts at h0, never decreases
-        assert r.max_off >= 0
-        # batch agrees
-        rb = bsw_extend_batch([q], [t], [h0], p)[0]
-        assert r == rb
-else:
-    @pytest.mark.skip(reason="hypothesis not installed")
-    def test_property_single_pair():
-        pass
+@settings(max_examples=30, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.integers(1, 80), st.integers(1, 80),
+       st.integers(1, 60))
+def test_property_single_pair(seed, ql, tl, h0):
+    """Invariants: score >= h0 is NOT guaranteed (zdrop), but score >=
+    the best row max seen; qle/tle within bounds; gscore <= score +
+    clip room."""
+    rng = np.random.default_rng(seed)
+    q = rng.integers(0, 4, size=ql).astype(np.uint8)
+    t = rng.integers(0, 4, size=tl).astype(np.uint8)
+    p = BSWParams()
+    r = bsw_extend(q, t, h0, p)
+    assert 0 <= r.qle <= ql
+    assert 0 <= r.tle <= tl
+    assert 0 <= r.gtle <= tl
+    assert r.score >= h0        # max_ starts at h0, never decreases
+    assert r.max_off >= 0
+    # batch agrees
+    rb = bsw_extend_batch([q], [t], [h0], p)[0]
+    assert r == rb
 
 
 def test_perfect_match_score():

@@ -11,11 +11,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # degrade gracefully: property tests skip
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.api import Aligner, engines, get_engine
 from repro.core import fmindex as fmx
@@ -202,24 +198,19 @@ def test_bsw_band_width_one():
     assert got == want
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=25, deadline=None)
-    @given(st.integers(0, 2**31 - 1), st.integers(1, 5))
-    def test_property_narrow_band_roundtrip(seed, w):
-        """Random narrow-band tasks: Pallas kernel == scalar oracle."""
-        rng = np.random.default_rng(seed)
-        ql = int(rng.integers(1, 30))
-        tl = int(rng.integers(1, 36))
-        q = rng.integers(0, 5, ql).astype(np.uint8)
-        t = rng.integers(0, 5, tl).astype(np.uint8)
-        h0 = int(rng.integers(1, 40))
-        got = bsw_extend_pallas([q], [t], [h0], BSWParams(), ws=[w])[0]
-        assert got == bsw_extend(q, t, h0, BSWParams(),
-                                 adjusted_band(ql, BSWParams(), w))
-else:
-    @pytest.mark.skip(reason="hypothesis not installed")
-    def test_property_narrow_band_roundtrip():
-        pass
+@settings(max_examples=25, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.integers(1, 5))
+def test_property_narrow_band_roundtrip(seed, w):
+    """Random narrow-band tasks: Pallas kernel == scalar oracle."""
+    rng = np.random.default_rng(seed)
+    ql = int(rng.integers(1, 30))
+    tl = int(rng.integers(1, 36))
+    q = rng.integers(0, 5, ql).astype(np.uint8)
+    t = rng.integers(0, 5, tl).astype(np.uint8)
+    h0 = int(rng.integers(1, 40))
+    got = bsw_extend_pallas([q], [t], [h0], BSWParams(), ws=[w])[0]
+    assert got == bsw_extend(q, t, h0, BSWParams(),
+                             adjusted_band(ql, BSWParams(), w))
 
 
 # ---------------------------------------------------------------------
@@ -261,3 +252,46 @@ def test_interpret_resolution(monkeypatch):
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         assert kcfg.resolve_interpret(True) is True
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and no other
+    directory is configured; otherwise the fixed <repo>/.jax_cache.  The
+    tests' own switch-off (conftest) is respected."""
+    import jax
+    assert kcfg.enable_compile_cache() is None         # off in tests
+    saved = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs")}
+    try:
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                               str(tmp_path / env_dir))
+            assert kcfg.enable_compile_cache() == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir is None
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = str(kcfg.REPO_ROOT / ".jax_cache")
+            assert kcfg.enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+
+
+@pytest.mark.parametrize("engine,kernel_interpret,mode", [
+    ("pallas", None, "interpret"), ("pallas", True, "interpret"),
+    ("pallas", False, "compiled"), ("batched", None, None),
+    ("baseline", False, None)])
+def test_device_summary(engine, kernel_interpret, mode):
+    import jax
+    d = kcfg.device_summary(engine, kernel_interpret)
+    want = {"platform": jax.devices()[0].platform,
+            "kind": jax.devices()[0].device_kind, "count": len(jax.devices())}
+    if mode is not None:
+        want["kernel_mode"] = mode
+    assert d == want

@@ -2,11 +2,7 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # degrade gracefully: property tests skip
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro.core import fmindex as fmx
 from repro.data import make_reference
@@ -46,6 +42,15 @@ def test_suffix_array_sorted(idx):
         a = S[sa[i]:sa[i] + 50].tobytes()
         b = S[sa[i + 1]:sa[i + 1] + 50].tobytes()
         assert a <= b
+
+
+@pytest.mark.parametrize("packed", [True, False])
+def test_suffix_array_sorts_agree_with_naive(packed):
+    """Both rank-pair sorts (packed int64 key; lexsort for long
+    sequences) give the naive suffix order, sentinel row first."""
+    s = make_reference(700, seed=5)[:700]
+    naive = sorted(range(len(s) + 1), key=lambda i: s[i:].tobytes())
+    np.testing.assert_array_equal(fmx.suffix_array(s, packed=packed), naive)
 
 
 def test_exact_search_counts(idx):
@@ -115,35 +120,41 @@ def test_vectorized_extension(idx):
                 assert (int(fk[j]), int(fl[j])) == (e[0], e[1])
 
 
-if HAVE_HYPOTHESIS:
-    @settings(max_examples=20, deadline=None)
-    @given(st.integers(0, 2**31 - 1), st.integers(40, 300))
-    def test_property_random_reference(seed, n):
-        """Index invariants on arbitrary references (hypothesis)."""
-        rng = np.random.default_rng(seed)
-        ref = rng.integers(0, 4, size=n, dtype=np.uint8)
-        idx = fmx.build_index(ref)
-        # C counts are consistent with the sequence
-        S = idx.seq
-        counts = np.bincount(S, minlength=4)
-        assert idx.C[0] == 1
-        for c in range(1, 4):
-            assert idx.C[c] - idx.C[c - 1] == counts[c - 1]
-        # occ at the end counts everything
-        for c in range(4):
-            assert idx.occ(c, idx.N - 1) == counts[c]
-        # SAL identity on a sample of rows
-        rs = rng.integers(0, idx.N, size=16)
-        for i in rs:
-            v, _ = idx.sa_lookup_compressed(int(i))
-            assert v == idx.sa_lookup(int(i))
-else:
-    @pytest.mark.skip(reason="hypothesis not installed")
-    def test_property_random_reference():
-        pass
+@settings(max_examples=20, deadline=None)
+@given(st.integers(0, 2**31 - 1), st.integers(40, 300))
+def test_property_random_reference(seed, n):
+    """Index invariants on arbitrary references (hypothesis)."""
+    rng = np.random.default_rng(seed)
+    ref = rng.integers(0, 4, size=n, dtype=np.uint8)
+    idx = fmx.build_index(ref)
+    # C counts are consistent with the sequence
+    S = idx.seq
+    counts = np.bincount(S, minlength=4)
+    assert idx.C[0] == 1
+    for c in range(1, 4):
+        assert idx.C[c] - idx.C[c - 1] == counts[c - 1]
+    # occ at the end counts everything
+    for c in range(4):
+        assert idx.occ(c, idx.N - 1) == counts[c]
+    # SAL identity on a sample of rows
+    rs = rng.integers(0, idx.N, size=16)
+    for i in rs:
+        v, _ = idx.sa_lookup_compressed(int(i))
+        assert v == idx.sa_lookup(int(i))
 
 
 def test_revcomp_involution():
     rng = np.random.default_rng(5)
     x = rng.integers(0, 4, size=100, dtype=np.uint8)
     assert (fmx.revcomp(fmx.revcomp(x)) == x).all()
+
+
+def test_device_view_per_default_device(idx):
+    """One device view per device: the default device and the same
+    device named explicitly share a view (no second upload)."""
+    import jax
+    view = idx.device()
+    assert idx.device() is view
+    with jax.default_device(jax.devices()[0]):
+        assert idx.device() is view
+    assert list(idx._device) == [jax.devices()[0]]

@@ -10,11 +10,7 @@ import gzip
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:          # degrade gracefully: property tests skip
-    HAVE_HYPOTHESIS = False
+from hypothesis import given, settings, strategies as st
 
 from repro import cli
 from repro.core import build_contig_index, sam_header
@@ -193,45 +189,39 @@ def test_write_fastq_pair_suffixes(world):
 # hypothesis round-trip properties
 # ---------------------------------------------------------------------
 
-if HAVE_HYPOTHESIS:
-    _name = st.text(st.characters(min_codepoint=33, max_codepoint=126,
-                                  exclude_characters="@>"),
-                    min_size=1, max_size=12)
-    _seq = st.text(st.sampled_from("ACGTNacgtnRYSWKMbdhv"), min_size=1,
-                   max_size=80)
+_name = st.text(st.characters(min_codepoint=33, max_codepoint=126,
+                              exclude_characters="@>"),
+                min_size=1, max_size=12)
+_seq = st.text(st.sampled_from("ACGTNacgtnRYSWKMbdhv"), min_size=1,
+               max_size=80)
 
-    @st.composite
-    def _fastq_record(draw):
-        seq = draw(_seq)
-        qual = draw(st.text(st.characters(min_codepoint=33,
-                                          max_codepoint=126),
-                            min_size=len(seq), max_size=len(seq)))
-        return FastqRecord(draw(_name), seq, qual)
 
-    @settings(max_examples=15, deadline=None)
-    @given(st.lists(st.tuples(_name, _seq), min_size=1, max_size=6),
-           st.booleans(), st.integers(1, 90))
-    def test_property_fasta_roundtrip(tmp_path_factory, recs, gz, width):
-        d = tmp_path_factory.mktemp("hfa")
-        path = str(d / ("x.fa.gz" if gz else "x.fa"))
-        iofasta.write_fasta(path, recs, width=width)
-        assert read_fasta(path) == [(n, s) for n, s in recs]
+@st.composite
+def _fastq_record(draw):
+    seq = draw(_seq)
+    qual = draw(st.text(st.characters(min_codepoint=33,
+                                      max_codepoint=126),
+                        min_size=len(seq), max_size=len(seq)))
+    return FastqRecord(draw(_name), seq, qual)
 
-    @settings(max_examples=15, deadline=None)
-    @given(st.lists(_fastq_record(), min_size=1, max_size=6), st.booleans())
-    def test_property_fastq_roundtrip(tmp_path_factory, recs, gz):
-        d = tmp_path_factory.mktemp("hfq")
-        path = str(d / ("x.fq.gz" if gz else "x.fq"))
-        iofastq.write_fastq(path, recs)
-        assert list(read_fastq(path)) == recs
-else:
-    @pytest.mark.skip(reason="hypothesis not installed")
-    def test_property_fasta_roundtrip():
-        pass
 
-    @pytest.mark.skip(reason="hypothesis not installed")
-    def test_property_fastq_roundtrip():
-        pass
+@settings(max_examples=15, deadline=None)
+@given(st.lists(st.tuples(_name, _seq), min_size=1, max_size=6),
+       st.booleans(), st.integers(1, 90))
+def test_property_fasta_roundtrip(tmp_path_factory, recs, gz, width):
+    d = tmp_path_factory.mktemp("hfa")
+    path = str(d / ("x.fa.gz" if gz else "x.fa"))
+    iofasta.write_fasta(path, recs, width=width)
+    assert read_fasta(path) == [(n, s) for n, s in recs]
+
+
+@settings(max_examples=15, deadline=None)
+@given(st.lists(_fastq_record(), min_size=1, max_size=6), st.booleans())
+def test_property_fastq_roundtrip(tmp_path_factory, recs, gz):
+    d = tmp_path_factory.mktemp("hfq")
+    path = str(d / ("x.fq.gz" if gz else "x.fq"))
+    iofastq.write_fastq(path, recs)
+    assert list(read_fastq(path)) == recs
 
 
 # ---------------------------------------------------------------------

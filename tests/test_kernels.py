@@ -103,6 +103,6 @@ def test_bsw_kernel_vs_padded_ref_interface():
         [qs[i, :qlens[i]].astype(np.uint8) for i in range(W)],
         [ts[i, :tlens[i]].astype(np.uint8) for i in range(W)],
         h0s.tolist(), p, ws=ws.tolist())
-    got_arr = np.stack([[r.score, r.qle, r.tle, r.gtle, r.gscore,
-                         r.max_off] for r in got], axis=1)
+    got_arr = np.array([[r.score, r.qle, r.tle, r.gtle, r.gscore,
+                         r.max_off] for r in got])
     assert (got_arr == want).all()

@@ -85,6 +85,26 @@ def test_runlog_roundtrip_and_envelope(tmp_path):
     assert events[2]["status"] == "ok"
 
 
+@pytest.mark.parametrize("engine", ["batched", "baseline"])
+def test_cli_mem_manifest_records_device(world, tmp_path, engine):
+    """``repro.cli mem`` says where it ran; engines that dispatch no
+    Pallas kernel record no kernel mode."""
+    import jax
+    from repro.data.reads import write_fasta
+    idx, _, fq = world
+    fa = tmp_path / "ref.fa"
+    write_fasta(fa, [("chr1", make_reference(20000, seed=7))])
+    rl = tmp_path / "run.jsonl"
+    assert cli_main(["mem", str(fa), fq, "--engine", engine, "--no-pg",
+                     "-o", str(tmp_path / "out.sam"),
+                     "--runlog", str(rl)]) == 0
+    man = obs.read_runlog(rl)[0]
+    assert man["event"] == "run_start"
+    assert man["device"] == {"platform": jax.devices()[0].platform,
+                             "kind": jax.devices()[0].device_kind,
+                             "count": len(jax.devices())}
+
+
 def test_runlog_rejects_malformed_files(tmp_path):
     good = {"v": obs.RUNLOG_VERSION, "run": "r1", "seq": 0, "t": 0.0,
             "ts": 0.0, "event": "run_start"}
