@@ -39,7 +39,7 @@ from .bsw import BSWParams, ExtResult, bsw_extend, bsw_extend_tasks
 from .chain import Chain, ChainOptions, chain_seeds, filter_chains
 from .contig import block_bounds, contig_edges
 from .fmindex import FMIndex, occ_opt_np, occ_opt_v
-from .sam import global_align_cigar, format_sam
+from .sam import band_cells, global_align_cigar, format_sam
 from .smem import MemOptions
 
 MAX_BAND_TRY = 2
@@ -376,8 +376,13 @@ def finalize_alignment(a: Alignment, query: np.ndarray, S: np.ndarray,
                        l_pac: int, p: BSWParams):
     qseg = query[a.qb:a.qe]
     tseg = S[a.rb:a.re]
-    _, cig = global_align_cigar(np.clip(qseg, 0, 4), np.clip(tseg, 0, 4),
-                                a.w, p)
+    if obs.enabled():
+        obs.count("finalize_alignments")
+        obs.count("finalize_cigar_cells",
+                  band_cells(len(qseg), len(tseg), a.w))
+    with obs.span("finalize.cigar"):
+        _, cig = global_align_cigar(np.clip(qseg, 0, 4),
+                                    np.clip(tseg, 0, 4), a.w, p)
     a.is_rev = a.rb >= l_pac
     if a.is_rev:
         a.pos = 2 * l_pac - a.re
@@ -571,14 +576,18 @@ def run_se_batched(idx: FMIndex, reads: np.ndarray,
                                batch_fn=bsw_batch_fn(opt))
     with obs.span("bsw", jobs=len(jobs)):
         execu.plan_and_run(jobs)
-    # Stage 5: decision replay + SAM-FORM
+    # Stage 5: decision replay, then marking, MAPQ, CIGAR and NM
     with obs.span("finalize"):
+        with obs.span("finalize.replay"):
+            alns_per_read = []
+            for r in range(R):
+                alns: list[Alignment] = []
+                for ci, c in enumerate(chains_per_read[r]):
+                    alns.extend(chain2aln(c, reads[r], idx, opt.bsw,
+                                          execu.executor((r, ci))))
+                alns_per_read.append(alns)
         results = []
-        for r in range(R):
-            alns: list[Alignment] = []
-            for ci, c in enumerate(chains_per_read[r]):
-                alns.extend(chain2aln(c, reads[r], idx, opt.bsw,
-                                      execu.executor((r, ci))))
+        for r, alns in enumerate(alns_per_read):
             frep = smem_mod.frac_rep(mems[r], L, opt.mem.max_occ)
             results.append(mark_and_finalize(alns, reads[r], S, l_pac,
                                              opt.bsw, opt.mem.min_seed_len,

@@ -15,6 +15,21 @@ from .contig import DEFAULT_RNAME, translate
 _OPS = "MID"
 
 
+def band_cells(n: int, m: int, w: int) -> int:
+    """Cells ``global_align_cigar`` fills for a query of ``n`` and a
+    target of ``m`` bases at band ``w``: the sum over its rows i = 1..n
+    of ``jhi - jlo + 1``, with ``w`` widened as it widens it, in closed
+    form (every row holds at least one cell)."""
+    if n == 0 or m == 0:
+        return 0
+    w = max(w, abs(n - m) + 3)
+    a = min(max(m - w, 0), n)           # rows whose band ends at i + w
+    hi = a * (a + 1) // 2 + a * w + (n - a) * m
+    c = min(n, w + 1)                   # rows whose band starts at 1
+    lo = c + n * (n + 1) // 2 - c * (c + 1) // 2 - (n - c) * w
+    return hi - lo + n
+
+
 def global_align_cigar(q: np.ndarray, t: np.ndarray, w: int,
                        p: BSWParams) -> tuple[int, list[tuple[int, str]]]:
     """Banded global affine-gap alignment with traceback -> (score, cigar).

@@ -1,12 +1,11 @@
 """jit'd wrappers: Pallas-backed occ and full backward extension.
 
 The public entry points (``occ_pallas`` / ``backward_ext_pallas``) are
-plain Python wrappers around the jitted implementations so telemetry can
-run OUTSIDE the jit boundary — a jitted body only executes Python at
-trace time, so spans/counters placed inside it would record nothing on
-cached calls.  With telemetry off the wrappers add one thread-local read;
-with it on they count device dispatches and time the call to completion
-(``block_until_ready``, so the span measures compute, not dispatch).
+plain Python wrappers that resolve ``interpret`` and call the jitted
+implementations; the kernel tests drive them.  The pipeline does not:
+the SMEM search jits its own round around ``make_occ_fn``'s callable,
+and its ``kernel.fmocc`` span and dispatch count live there
+(``core.smem._ext_round``).
 
 ``interpret`` resolves from the active JAX backend when left ``None``
 (interpret on CPU, compiled on TPU/GPU — see ``kernels.config``).
@@ -27,7 +26,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro import obs
 from repro.core.fmindex import FMArrays, I32
 from ..config import resolve_interpret
 from .kernel import (occ_count_pallas_call, occ_count_packed_pallas_call,
@@ -142,24 +140,12 @@ def occ_pallas(fm: FMArrays, c: jnp.ndarray, i: jnp.ndarray, *,
                layout: str = "eta32", qb: int = QB,
                interpret: bool | None = None) -> jnp.ndarray:
     """Public Occ(c, i) entry point (see module docstring)."""
-    itp = resolve_interpret(interpret)
-    if not obs.enabled():
-        return _occ_pallas_jit(fm, c, i, layout=layout, qb=qb, interpret=itp)
-    with obs.span("kernel.fmocc", cat="kernel"):
-        obs.count("kernel_fmocc_dispatches")
-        out = _occ_pallas_jit(fm, c, i, layout=layout, qb=qb, interpret=itp)
-        jax.block_until_ready(out)
-    return out
+    return _occ_pallas_jit(fm, c, i, layout=layout, qb=qb,
+                           interpret=resolve_interpret(interpret))
 
 
 def backward_ext_pallas(fm: FMArrays, k, l, s, c, *,
                         interpret: bool | None = None):
     """Public backward-extension entry point (see module docstring)."""
-    itp = resolve_interpret(interpret)
-    if not obs.enabled():
-        return _backward_ext_pallas_jit(fm, k, l, s, c, interpret=itp)
-    with obs.span("kernel.fmocc_bwd", cat="kernel"):
-        obs.count("kernel_fmocc_dispatches")
-        out = _backward_ext_pallas_jit(fm, k, l, s, c, interpret=itp)
-        jax.block_until_ready(out)
-    return out
+    return _backward_ext_pallas_jit(fm, k, l, s, c,
+                                    interpret=resolve_interpret(interpret))

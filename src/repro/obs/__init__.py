@@ -29,7 +29,7 @@ from .report import (SHARD_INVARIANT_COUNTERS, STAGES, breakdown,
 from .runlog import (RUNLOG_VERSION, RunLog, index_fingerprint, new_run_id,
                      read_runlog)
 from .trace import (NULL_SPAN, Telemetry, TraceCollector, activate, count,
-                    current, enabled, observe, set_gauge, span)
+                    current, enabled, observe, record, set_gauge, span)
 
 __all__ = [
     "DEFAULT_EDGES", "RATIO_EDGES", "Gauge", "Hist", "MetricsRegistry",
@@ -41,5 +41,5 @@ __all__ = [
     "RUNLOG_VERSION", "RunLog", "index_fingerprint", "new_run_id",
     "read_runlog",
     "NULL_SPAN", "Telemetry", "TraceCollector", "activate", "count",
-    "current", "enabled", "observe", "set_gauge", "span",
+    "current", "enabled", "observe", "record", "set_gauge", "span",
 ]

@@ -20,6 +20,15 @@ Two cooperating pieces:
 whole ``stream_sam`` loop (catching I/O-side instrumentation) and a
 fresh per-call registry inside each ``align`` call (so per-batch stats
 merge associatively), restoring the outer scope on exit.
+
+``record`` takes an interval whose two ends were stamped apart (a
+request's queue wait: put on one thread, taken on another).  With a
+tracer active each ``span`` also opens a ``jax.profiler``
+``TraceAnnotation`` of its name, so a profiler session shows the
+program's stages beside the device's operations; and the first
+``Telemetry`` built registers a ``jax.monitoring`` listener that turns
+every XLA compile on a thread with an active scope into a ``compile``
+span and a ``compiles`` count.
 """
 
 from __future__ import annotations
@@ -111,6 +120,37 @@ class TraceCollector:
             return len(self.events)
 
 
+#: JAX reports this duration around each program it builds (compiled,
+#: or loaded from the persistent cache), on the building thread
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_compile(event: str, secs: float, **kw) -> None:
+    """``jax.monitoring`` duration listener: a ``compile`` span ending
+    now and lasting ``secs``, plus the ``compiles`` counter, on the
+    compiling thread's scope (one thread-local read when there is none)."""
+    act = getattr(_TLS, "active", None)
+    if act is None or event != COMPILE_EVENT:
+        return
+    t1 = time.perf_counter()
+    record("compile", t1 - secs, t1, cat="compile",
+           fun=kw.get("fun_name", "?"))
+    count("compiles")
+
+
+def _listen_for_compiles() -> None:
+    global _compile_listener_on
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener_on = True
+
+
 class Telemetry:
     """Per-``Aligner`` telemetry configuration + the run-long trace
     buffer.  Metrics registries are per-call (opened by the facade so
@@ -120,6 +160,7 @@ class Telemetry:
 
     def __init__(self, *, trace: bool = False, max_events: int = 1_000_000):
         self.tracer = TraceCollector(max_events) if trace else None
+        _listen_for_compiles()
 
     def activate(self, registry: MetricsRegistry | None = None):
         """Context manager: make (registry, self.tracer) ambient for the
@@ -159,21 +200,29 @@ def activate(registry: MetricsRegistry | None,
 
 class _Span:
     """Timed scope: duration lands on the ambient registry as a
-    ``time_<name>_s`` counter AND on the tracer as a trace event."""
-    __slots__ = ("_act", "_name", "_cat", "_args", "_t0")
+    ``time_<name>_s`` counter AND on the tracer as a trace event (and,
+    with a tracer, in the profiler's trace as an annotation)."""
+    __slots__ = ("_act", "_name", "_cat", "_args", "_t0", "_ann")
 
     def __init__(self, act, name, cat, args):
         self._act = act
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = None
 
     def __enter__(self):
+        if self._act.tracer is not None:
+            from jax.profiler import TraceAnnotation
+            self._ann = TraceAnnotation(self._name)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         act = self._act
         if act.registry is not None:
             act.registry.add_time(self._name, dur)
@@ -193,6 +242,21 @@ def span(name: str, cat: str = "stage", **args):
     if act is None:
         return NULL_SPAN
     return _Span(act, name, cat, args or None)
+
+
+def record(name: str, t0: float, t1: float, cat: str = "stage",
+           **args) -> None:
+    """A span with explicit ends: ``t0`` and ``t1`` are
+    ``time.perf_counter()`` stamps, possibly taken on other threads.  It
+    lands on the calling thread's scope as ``span`` does (no-op when
+    off)."""
+    act = getattr(_TLS, "active", None)
+    if act is None:
+        return
+    if act.registry is not None:
+        act.registry.add_time(name, t1 - t0)
+    if act.tracer is not None:
+        act.tracer.complete(name, t0, t1 - t0, cat, args or None)
 
 
 def count(name: str, n=1) -> None:

@@ -55,6 +55,7 @@ class Request:
     deadline: float | None        # absolute time.monotonic() deadline
     conn: Any                     # _Conn owning the response stream
     received: float = dataclasses.field(default_factory=time.monotonic)
+    enqueued: float = 0.0         # time.perf_counter() when queued
 
     @property
     def n_reads(self) -> int:
@@ -97,6 +98,7 @@ class RequestQueue:
                 raise QueueClosed()
             if len(self._items) >= self.maxsize:
                 raise Overloaded(f"queue full ({self.maxsize} requests)")
+            req.enqueued = time.perf_counter()
             self._items.append(req)
             self._nonempty.notify()
 

@@ -29,7 +29,15 @@ coalesce-width/pad-waste hists, request/error counters) merged with the
 per-batch engine Snapshots feeds an optional ``obs.LiveExporter``
 (Prometheus textfile + JSON, rewritten while serving) and an optional
 ``obs.RunLog`` records ``request`` / ``batch_coalesced`` /
-``request_done`` / ``request_error`` events.
+``request_done`` / ``request_error`` events.  With telemetry on, the
+scheduler thread runs under a scope holding that registry and the
+telemetry's tracer, and records each request's lifecycle as spans:
+``serve.queue_wait`` per request, from its enqueue to when its batch was
+formed (args ``rid`` and ``peer``, which name the request, ``batch``,
+``reads``); ``serve.batch`` per engine batch, from its formation to the
+last response sent (args ``batch``, ``requests``, ``reads``), holding
+``serve.engine`` (the aligner call, the pipeline's stage spans inside),
+``serve.sam`` and ``serve.respond`` (the split and the frame sends).
 """
 
 from __future__ import annotations
@@ -92,7 +100,8 @@ class AlignmentServer:
                  host: str = "127.0.0.1", port: int = 0,
                  max_batch_reads: int = 512, max_queue: int = 64,
                  max_read_len: int = MAX_READ_LEN,
-                 pe_stats=None, telemetry: bool = True,
+                 pe_stats=None,
+                 telemetry: "obs.Telemetry | bool | None" = True,
                  runlog: "obs.RunLog | None" = None,
                  exporter: "obs.LiveExporter | None" = None):
         self.index = index
@@ -102,7 +111,9 @@ class AlignmentServer:
         self.max_batch_reads = max(1, int(max_batch_reads))
         self.max_read_len = int(max_read_len)
         self.pe_stats = None if pe_stats is None else list(pe_stats)
-        self.telemetry = telemetry
+        if telemetry is True:
+            telemetry = obs.Telemetry()
+        self.telemetry: obs.Telemetry | None = telemetry or None
         self.runlog = runlog
         self.exporter = exporter
         self.queue = RequestQueue(maxsize=max_queue)
@@ -119,6 +130,7 @@ class AlignmentServer:
         self._conns: set[_Conn] = set()
         self._conns_lock = threading.Lock()
         self._drained = threading.Event()
+        self._batch_ids = 0                     # scheduler thread only
 
     # -- lifecycle --
 
@@ -358,30 +370,49 @@ class AlignmentServer:
 
     def _scheduler_loop(self) -> None:
         try:
-            while True:
-                try:
-                    req = self.queue.get()
-                except QueueClosed:
-                    return
-                self._gate.wait()
-                coalesce_pe = self.pe_stats is not None
-                key = req.cohort_key(coalesce_pe)
-                group = [req] + self.queue.take_cohort(
-                    key, coalesce_pe,
-                    budget_reads=self.max_batch_reads - req.n_reads)
-                self.metrics.set_gauge("serve_queue_depth", len(self.queue))
-                try:
-                    self._process_group(group)
-                except Exception as e:          # engine bug: fail the group
-                    if self.runlog is not None:
-                        self.runlog.crash(e)
-                    for r in group:
-                        self._send_error(r, protocol.ERR_INTERNAL,
-                                         f"{type(e).__name__}: {e}")
+            if self.telemetry is None:
+                self._schedule()
+            else:
+                with self.telemetry.activate(self.metrics):
+                    self._schedule()
         finally:
             self._drained.set()
 
-    def _process_group(self, group: list[Request]) -> None:
+    def _schedule(self) -> None:
+        while True:
+            try:
+                req = self.queue.get()
+            except QueueClosed:
+                return
+            self._gate.wait()
+            coalesce_pe = self.pe_stats is not None
+            key = req.cohort_key(coalesce_pe)
+            group = [req] + self.queue.take_cohort(
+                key, coalesce_pe,
+                budget_reads=self.max_batch_reads - req.n_reads)
+            formed = time.perf_counter()
+            bid = self._batch_ids
+            self._batch_ids += 1
+            self.metrics.set_gauge("serve_queue_depth", len(self.queue))
+            if obs.enabled():
+                for r in group:
+                    obs.record("serve.queue_wait", r.enqueued, formed,
+                               cat="serve", rid=r.id, peer=r.conn.peer,
+                               batch=bid, reads=r.n_reads)
+            try:
+                self._process_group(group, bid)
+            except Exception as e:              # engine bug: fail the group
+                if self.runlog is not None:
+                    self.runlog.crash(e)
+                for r in group:
+                    self._send_error(r, protocol.ERR_INTERNAL,
+                                     f"{type(e).__name__}: {e}")
+            if obs.enabled():
+                obs.record("serve.batch", formed, time.perf_counter(),
+                           cat="serve", batch=bid, requests=len(group),
+                           reads=sum(r.n_reads for r in group))
+
+    def _process_group(self, group: list[Request], bid: int) -> None:
         live = []
         for r in group:
             if r.expired():
@@ -398,37 +429,37 @@ class AlignmentServer:
         aligner = self._aligner_for(first.options)
         t0 = time.perf_counter()
         n_reads = sum(r.n_reads for r in live)
+        names = [n for r in live for n in r.names]
         if first.op == "align":
-            names = [n for r in live for n in r.names]
-            seqs = [s for r in live for s in r.seqs]
-            batch = _pack_se(names, seqs)
-            res = aligner.align(batch, engine=first.engine)
+            batch = _pack_se(names, [s for r in live for s in r.seqs])
+            run = aligner.align
+        else:
+            batch = _pack_pe(names, [s[0] for r in live for s in r.seqs],
+                             [s[1] for r in live for s in r.seqs])
+            run = aligner.align_pairs
+        with obs.span("serve.engine", cat="serve", batch=bid):
+            res = run(batch, engine=first.engine)
+        if first.op == "align":
             # one SAM line per emitted alignment, or one unmapped
             # placeholder — the exact per-read layout of the offline run
             counts = [max(1, len(a)) for a in res.alignments]
         else:
-            names = [n for r in live for n in r.names]
-            s1 = [s[0] for r in live for s in r.seqs]
-            s2 = [s[1] for r in live for s in r.seqs]
-            batch = _pack_pe(names, s1, s2)
-            res = aligner.align_pairs(batch, engine=first.engine)
             counts = [2] * (n_reads // 2)       # emit_pair: 2 lines/pair
         wall = time.perf_counter() - t0
-        lines = res.sam()
+        with obs.span("serve.sam", cat="serve", batch=bid):
+            lines = res.sam()
         self._note_batch(live, first, batch, n_reads, len(lines), wall,
                          res.stats)
-        # split the batch's SAM stream back per request, FIFO
-        edges = []
-        pos = 0
-        ci = 0
-        for r in live:
-            n_items = len(r.seqs)
-            n_lines = sum(counts[ci:ci + n_items])
-            edges.append((pos, pos + n_lines))
-            pos += n_lines
-            ci += n_items
-        for r, (lo, hi) in zip(live, edges):
-            self._respond(r, aligner, lines[lo:hi])
+        with obs.span("serve.respond", cat="serve", batch=bid):
+            # split the batch's SAM stream back per request, FIFO
+            pos = 0
+            ci = 0
+            for r in live:
+                n_items = len(r.seqs)
+                n_lines = sum(counts[ci:ci + n_items])
+                self._respond(r, aligner, lines[pos:pos + n_lines])
+                pos += n_lines
+                ci += n_items
 
     def _respond(self, r: Request, aligner: Aligner,
                  lines: list[str]) -> None:

@@ -353,3 +353,138 @@ def test_straggler_observe_external_times():
                          step_time=0.02 if i < 10 else 0.08) or ev
     assert ev is not None and ev.action in ("rebalance", "checkpoint")
     assert ev.step_time == pytest.approx(0.08)
+
+
+# ---------------------------------------------------------------------
+# explicit spans, finalize's parts, compiles, profiler annotations
+# ---------------------------------------------------------------------
+
+def test_record_lands_on_registry_and_tracer():
+    obs.record("gap", 1.0, 3.5, cat="serve", rid="r1")   # off: no-op
+    tel = obs.Telemetry(trace=True)
+    with tel.activate() as reg:
+        t0 = tel.tracer._epoch + 0.25
+        obs.record("gap", t0, t0 + 0.5, cat="serve", rid="r1")
+    assert reg.snapshot()["time_gap_s"] == pytest.approx(0.5)
+    (ev,) = tel.tracer.to_dict()["traceEvents"]
+    assert ev["name"] == "gap" and ev["cat"] == "serve"
+    assert ev["ts"] == pytest.approx(0.25e6)
+    assert ev["dur"] == pytest.approx(0.5e6)
+    assert ev["args"] == {"rid": "r1"}
+
+
+def test_span_annotates_the_profiler_only_with_a_tracer(monkeypatch):
+    import jax.profiler
+    entered = []
+
+    class Spy:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            entered.append("/" + self.name)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+    with obs.span("off"):
+        pass
+    with obs.activate(obs.MetricsRegistry()):
+        with obs.span("stats_only"):
+            pass
+    with obs.Telemetry(trace=True).activate():
+        with obs.span("outer"):
+            with obs.span("inner"):
+                pass
+    assert entered == ["outer", "inner", "/inner", "/outer"]
+
+
+def test_finalize_parts_lie_inside_finalize(world):
+    idx, reads = world
+    tele = obs.Telemetry(trace=True)
+    res = Aligner.from_index(idx, AlignOptions(engine="batched"),
+                             telemetry=tele).align(reads)
+    st = res.stats
+    assert st["time_finalize.cigar_s"] > 0 and st["time_finalize.replay_s"] > 0
+    assert (st["time_finalize.cigar_s"] + st["time_finalize.replay_s"]
+            <= st["time_finalize_s"])
+    n_records = sum(len(a) for a in res.alignments)
+    assert st["finalize_alignments"] == n_records > 0
+    assert st["finalize_cigar_cells"] >= sum(
+        a.qe - a.qb for alns in res.alignments for a in alns)
+    evs = tele.tracer.to_dict()["traceEvents"]
+    (fin,) = [e for e in evs if e["name"] == "finalize"]
+    parts = [e for e in evs if e["name"].startswith("finalize.")]
+    assert {e["name"] for e in parts} == {"finalize.replay", "finalize.cigar"}
+    for e in parts:
+        assert fin["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= fin["ts"] + fin["dur"] + 1e-3
+    # the baseline driver's CIGARs are timed and counted too
+    base = Aligner.from_index(idx, AlignOptions(engine="baseline"),
+                              telemetry=True).align(reads)
+    assert base.stats["finalize_cigar_cells"] == st["finalize_cigar_cells"]
+    assert base.stats["time_finalize.cigar_s"] > 0
+
+
+def test_finalize_records_nothing_with_telemetry_off(world):
+    idx, reads = world
+    stats = Aligner.from_index(idx, AlignOptions(engine="batched")).align(
+        reads).stats
+    assert not [k for k in stats
+                if k.startswith(("finalize_", "time_", "compile"))]
+
+
+class _NpSpy:
+    """numpy, with every array ``np.full`` makes kept."""
+
+    def __init__(self):
+        self.full_arrays = []
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def full(self, *a, **kw):
+        arr = np.full(*a, **kw)
+        self.full_arrays.append(arr)
+        return arr
+
+
+@pytest.mark.parametrize("n, m, w", [
+    (40, 40, 5),          # equal lengths, narrow band
+    (40, 40, 100),        # equal lengths, band wider than the matrix
+    (37, 52, 20),         # n < m
+    (60, 41, 30),         # n > m
+    (30, 45, 4),          # band widened to abs(n - m) + 3
+    (1, 9, 0),            # one row
+])
+def test_cigar_cell_count_matches_the_dp(monkeypatch, n, m, w):
+    from repro.core import sam
+    from repro.core.bsw import BSWParams
+    rng = np.random.default_rng(n * 1000 + m)
+    q = rng.integers(0, 5, n).astype(np.uint8)
+    t = rng.integers(0, 5, m).astype(np.uint8)
+    spy = _NpSpy()
+    monkeypatch.setattr(sam, "np", spy)
+    sam.global_align_cigar(q, t, w, BSWParams())
+    H = spy.full_arrays[0]                 # H, then E and F
+    # a filled cell never holds the fill value: no score in the matrix is 0
+    filled = int((H[1:, 1:] != -(1 << 28)).sum())
+    assert sam.band_cells(n, m, w) == filled
+    assert sam.band_cells(0, m, w) == sam.band_cells(n, 0, w) == 0
+
+
+def test_compile_span_only_under_telemetry():
+    import jax
+    tele = obs.Telemetry(trace=True)       # registers the listener
+    x = np.arange(7.0)
+    jax.jit(lambda v: v * 3.0 + 1.0)(x).block_until_ready()   # off
+    assert len(tele.tracer) == 0
+    with tele.activate() as reg:
+        jax.jit(lambda v: v * 5.0 - 2.0)(x).block_until_ready()
+    snap = reg.snapshot()
+    assert snap["compiles"] == 1 and snap["time_compile_s"] > 0
+    (ev,) = tele.tracer.to_dict()["traceEvents"]
+    assert ev["name"] == "compile" and ev["cat"] == "compile"
+    assert ev["args"]["fun"] == "jit(<lambda>)"
+    assert ev["dur"] == pytest.approx(snap["time_compile_s"] * 1e6)

@@ -494,8 +494,11 @@ def test_runlog_and_live_export(tmp_path, world):
 
 def _merge_counters(snaps):
     total = obs.Snapshot.merge_all(snaps)
+    # ``compiles`` counts the programs JAX built during the call: the
+    # serial calls build them, the threaded ones find them built
     return {k: v for k, v in total.items()
-            if isinstance(v, (int, float)) and not k.startswith("time")}
+            if isinstance(v, (int, float)) and not k.startswith("time")
+            and k != "compiles"}
 
 
 @pytest.mark.parametrize("engine", ["batched", "pallas"])
@@ -550,3 +553,83 @@ def test_aligner_pe_thread_safety(world):
     for t in threads:
         t.join(timeout=300)
     assert all(o == serial.sam() for o in out)
+
+
+# ---------------------------------------------------------------------
+# Request lifecycle spans
+# ---------------------------------------------------------------------
+
+def _coalesced(srv, parts):
+    """Send ``parts`` as one request each into ONE engine batch."""
+    srv.pause()
+    results = [None] * len(parts)
+
+    def worker(i):
+        with ServeClient.connect(*srv.address) as c:
+            results[i] = c.align(parts[i])
+
+    threads = [threading.Thread(target=worker, args=(i,))
+               for i in range(len(parts))]
+    for t in threads:
+        t.start()
+    _wait_queued(srv, len(parts))
+    srv.resume()
+    for t in threads:
+        t.join(timeout=60)
+        assert not t.is_alive()
+    return results
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"] + 1e-3 and
+            child["ts"] + child["dur"] <= parent["ts"] + parent["dur"] + 1e-3)
+
+
+def test_lifecycle_spans_of_a_coalesced_batch(world):
+    idx, se, _ = world
+    tele = obs.Telemetry(trace=True)
+    srv = AlignmentServer(idx, telemetry=tele)
+    srv.start()
+    try:
+        parts = [se[:5], se[5:8], se[8:]]
+        results = _coalesced(srv, parts)
+    finally:
+        srv.shutdown()
+    for part, res in zip(parts, results):
+        assert res.sam == offline_se(idx, part)
+    evs = tele.tracer.to_dict()["traceEvents"]
+    (batch,) = [e for e in evs if e["name"] == "serve.batch"]
+    assert batch["args"] == {"batch": 0, "requests": 3, "reads": len(se)}
+    waits = [e for e in evs if e["name"] == "serve.queue_wait"]
+    assert len(waits) == 3
+    assert len({(e["args"]["peer"], e["args"]["rid"]) for e in waits}) == 3
+    assert sorted(e["args"]["reads"] for e in waits) == [3, 4, 5]
+    for e in waits:
+        assert e["args"]["batch"] == 0 and e["tid"] == batch["tid"]
+        assert e["ts"] + e["dur"] <= batch["ts"] + 1e-3
+    children = {}
+    for e in evs:
+        if e["name"] in ("serve.engine", "serve.sam", "serve.respond"):
+            assert e["args"] == {"batch": 0} and _inside(e, batch)
+            children[e["name"]] = e
+    assert set(children) == {"serve.engine", "serve.sam", "serve.respond"}
+    (smem,) = [e for e in evs if e["name"] == "smem"]
+    assert _inside(smem, children["serve.engine"])
+    snap = srv.metrics.snapshot()
+    assert snap["time_serve.batch_s"] >= snap["time_serve.engine_s"] > 0
+
+
+def test_server_telemetry_off_records_nothing(world):
+    idx, se, _ = world
+    tele = obs.Telemetry(trace=True)       # the compile listener is on
+    srv = AlignmentServer(idx, telemetry=False)
+    srv.start()
+    try:
+        results = _coalesced(srv, [se[:4], se[4:]])
+    finally:
+        srv.shutdown()
+    assert all(res.sam for res in results)
+    assert len(tele.tracer) == 0
+    new = [k for k in srv.live_stats()
+           if k.startswith(("time_", "compile", "finalize_"))]
+    assert new == []
