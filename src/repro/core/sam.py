@@ -36,47 +36,65 @@ def global_align_cigar(q: np.ndarray, t: np.ndarray, w: int,
 
     q aligned fully to t; band of half-width w around the diagonal scaled
     to the length difference (as ksw_global does).
+
+    Each row's band is filled with whole-row array operations.  F and the
+    diagonal read only the row above.  The deletion chain ``E[i, j] =
+    max(E[i, j-1] - e_del, H[i, j-1] - o_del - e_del)``, the one in-row
+    dependency, unrolls over ``jlo..j`` to ``max(E[i, jlo-1] - e_del *
+    (j-jlo+1), max_{jlo-1 <= k < j} (H[i, k] + e_del * k) - o_del - e_del
+    * j)``, a running maximum.  ``H[i, k]`` may be taken before E is
+    folded in (the diagonal and F alone): an E term fed back through H is
+    never above the E chain itself.
     """
     n, m = len(q), len(t)
     if n == 0:
         return (-p.o_del - p.e_del * m if m else 0), ([(m, "D")] if m else [])
     if m == 0:
         return -p.o_ins - p.e_ins * n, [(n, "I")]
-    mat = p.matrix()
+    # S[i - 1, j - 1] scores q[i - 1] against t[j - 1]
+    S = p.matrix()[np.ix_(q, t)]
     w = max(w, abs(n - m) + 3)
+    oe_del, oe_ins = p.o_del + p.e_del, p.o_ins + p.e_ins
     NEG = -(1 << 28)
     H = np.full((n + 1, m + 1), NEG, np.int64)
     E = np.full((n + 1, m + 1), NEG, np.int64)   # gap in query (deletion, consume t)
     F = np.full((n + 1, m + 1), NEG, np.int64)   # gap in target (insertion, consume q)
     H[0, 0] = 0
-    for j in range(1, min(m, w) + 1):
-        E[0, j] = -(p.o_del + p.e_del * j)
-        H[0, j] = E[0, j]
-    for i in range(1, min(n, w) + 1):
-        F[i, 0] = -(p.o_ins + p.e_ins * i)
-        H[i, 0] = F[i, 0]
+    j0 = min(m, w)
+    E[0, 1:j0 + 1] = -(p.o_del + p.e_del * np.arange(1, j0 + 1))
+    H[0, 1:j0 + 1] = E[0, 1:j0 + 1]
+    i0 = min(n, w)
+    F[1:i0 + 1, 0] = -(p.o_ins + p.e_ins * np.arange(1, i0 + 1))
+    H[1:i0 + 1, 0] = F[1:i0 + 1, 0]
+    edel = p.e_del * np.arange(m + 1)
     for i in range(1, n + 1):
         jlo = max(1, i - w)
         jhi = min(m, i + w)
-        for j in range(jlo, jhi + 1):
-            E[i, j] = max(E[i, j - 1] - p.e_del, H[i, j - 1] - p.o_del - p.e_del)
-            F[i, j] = max(F[i - 1, j] - p.e_ins, H[i - 1, j] - p.o_ins - p.e_ins)
-            diag = H[i - 1, j - 1] + mat[int(q[i - 1]), int(t[j - 1])]
-            H[i, j] = max(diag, E[i, j], F[i, j])
-    # traceback
+        Hp, Fp, Hi, Ei, Fi = H[i - 1], F[i - 1], H[i], E[i], F[i]
+        f = Fi[jlo:jhi + 1]
+        np.maximum(Fp[jlo:jhi + 1] - p.e_ins, Hp[jlo:jhi + 1] - oe_ins, out=f)
+        h = Hi[jlo:jhi + 1]
+        np.maximum(Hp[jlo - 1:jhi] + S[i - 1, jlo - 1:jhi], f, out=h)
+        run = np.maximum.accumulate(Hi[jlo - 1:jhi] + edel[jlo - 1:jhi])
+        e = Ei[jlo:jhi + 1]
+        np.maximum(Ei[jlo - 1] - edel[1:jhi - jlo + 2],
+                   run - p.o_del - edel[jlo:jhi + 1], out=e)
+        np.maximum(h, e, out=h)
+    # traceback, over plain lists (NumPy scalar reads cost more than
+    # the lists take to build)
+    H, E, F, S = H.tolist(), E.tolist(), F.tolist(), S.tolist()
     i, j = n, m
     ops: list[str] = []
     state = "H"
     while i > 0 or j > 0:
         if state == "H":
-            if i > 0 and j > 0 and H[i, j] == (
-                    H[i - 1, j - 1] + mat[int(q[i - 1]), int(t[j - 1])]):
+            if i > 0 and j > 0 and H[i][j] == H[i - 1][j - 1] + S[i - 1][j - 1]:
                 ops.append("M")
                 i -= 1
                 j -= 1
-            elif j > 0 and H[i, j] == E[i, j]:
+            elif j > 0 and H[i][j] == E[i][j]:
                 state = "E"
-            elif i > 0 and H[i, j] == F[i, j]:
+            elif i > 0 and H[i][j] == F[i][j]:
                 state = "F"
             else:  # out-of-band corner: force remaining as gaps
                 if i == 0:
@@ -87,12 +105,12 @@ def global_align_cigar(q: np.ndarray, t: np.ndarray, w: int,
                     ops.append("M"); i -= 1; j -= 1
         elif state == "E":
             ops.append("D")
-            if E[i, j] == H[i, j - 1] - p.o_del - p.e_del:
+            if E[i][j] == H[i][j - 1] - oe_del:
                 state = "H"
             j -= 1
         else:
             ops.append("I")
-            if F[i, j] == H[i - 1, j] - p.o_ins - p.e_ins:
+            if F[i][j] == H[i - 1][j] - oe_ins:
                 state = "H"
             i -= 1
     ops.reverse()
@@ -102,7 +120,7 @@ def global_align_cigar(q: np.ndarray, t: np.ndarray, w: int,
             cigar[-1] = (cigar[-1][0] + 1, op)
         else:
             cigar.append((1, op))
-    return int(H[n, m]), cigar
+    return H[n][m], cigar
 
 
 def _cigar_str(read: np.ndarray, aln, hard_clip: bool = False) -> str:

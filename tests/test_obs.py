@@ -459,14 +459,17 @@ class _NpSpy:
     (1, 9, 0),            # one row
 ])
 def test_cigar_cell_count_matches_the_dp(monkeypatch, n, m, w):
+    """``band_cells`` against the cells the cell-at-a-time DP fills (the
+    oracle of ``test_sam_cigar.py``, which the shipped DP matches)."""
+    import test_sam_cigar as oracle
     from repro.core import sam
     from repro.core.bsw import BSWParams
     rng = np.random.default_rng(n * 1000 + m)
     q = rng.integers(0, 5, n).astype(np.uint8)
     t = rng.integers(0, 5, m).astype(np.uint8)
     spy = _NpSpy()
-    monkeypatch.setattr(sam, "np", spy)
-    sam.global_align_cigar(q, t, w, BSWParams())
+    monkeypatch.setattr(oracle, "np", spy)
+    oracle.scalar_global_align_cigar(q, t, w, BSWParams())
     H = spy.full_arrays[0]                 # H, then E and F
     # a filled cell never holds the fill value: no score in the matrix is 0
     filled = int((H[1:, 1:] != -(1 << 28)).sum())
