@@ -14,6 +14,9 @@ Two implementations with IDENTICAL output (the paper's hard requirement):
   TPU it is the only way to keep the VPU busy and is the direct analogue of
   software prefetching — every O_c bucket needed by round r+1 is gathered
   in one vectorized load during round r's step.  See DESIGN.md §2.
+  With NumPy occ the rounds run on the host; with a jnp or Pallas occ
+  function each call's rounds run on the device as one ``lax.while_loop``
+  program, one dispatch and one readback per call.
 
 An SMEM is reported as (k, l, s, qbeg, qend): bi-interval + query span.
 """
@@ -26,6 +29,7 @@ from typing import Callable
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import obs
 from .fmindex import (FMIndex, backward_ext_np, backward_ext_v,
@@ -225,74 +229,27 @@ class SmemTaskBatch:
     ret: np.ndarray    # (T,) next x
 
 
-def _fwd_round(fm, k, l, s, c, occ_fn):
-    return forward_ext_v(fm, k, l, s, c, occ_fn=occ_fn)
-
-
-def _bwd_round(fm, k, l, s, c, occ_fn):
-    return backward_ext_v(fm, k, l, s, c, occ_fn=occ_fn)
-
-
-_fwd_round_j = jax.jit(_fwd_round, static_argnames=("occ_fn",))
-_bwd_round_j = jax.jit(_bwd_round, static_argnames=("occ_fn",))
-
 _NUMPY_OCC = (occ_opt_np, occ_base_np)
 
 
 def _bucket_tasks(T: int) -> int:
-    """Pad the live-task axis to a power of two (floor 32).
+    """Pad the task axis of a device loop to a power of two (floor 32).
 
-    The jitted rounds retrace per distinct (T, P) shape; as lockstep tasks
-    die off, T shrinks by arbitrary amounts each round, which with a
-    Pallas occ_fn would mean a kernel recompile per round.  Bucketing T
-    bounds the distinct shapes to O(log T).  Pad lanes use k=l=s=0, c=4 —
-    the same values dead-but-present lanes already carry through the
-    vectorized rounds, so results are unaffected.
+    A device loop compiles once per (tasks, read length) shape, and the
+    number of tasks differs from call to call (SMEM hops, re-seeding, the
+    third round's x-loop); bucketing it bounds the distinct programs to
+    O(log T).  Pad tasks have length 0, so they are never valid and take
+    no part in any round.
     """
     return max(32, 1 << (T - 1).bit_length())
 
 
-def _ext_round(idx: FMIndex, which: str, k, l, s, c, occ_fn):
-    """One vectorized extension round, numpy or jax backend.
-
-    The numpy backend (default) runs the identical integer math without
-    per-round device dispatch — the CPU-pipeline fast path.  The jax
-    backend is what a TPU host loop would use; occ_fns carrying
-    ``is_pallas`` (kernels.fmocc.make_occ_fn) route every occ lookup of
-    the round through the Pallas kernel and are counted/timed as kernel
-    dispatches."""
+def _ext_round(idx: FMIndex, which: str, k, l, s, c, occ_np):
+    """One vectorized extension round of the host (NumPy) lockstep path,
+    the CPU pipeline's fast path: no device dispatch at all."""
     obs.count("smem_rounds")
-    if occ_fn in _NUMPY_OCC:
-        fn = forward_ext_np if which == "fwd" else backward_ext_np
-        return fn(idx, k, l, s, c, occ_np=occ_fn)
-    obs.count("smem_occ_dispatches")
-    jf = _fwd_round_j if which == "fwd" else _bwd_round_j
-    k = np.asarray(k); l = np.asarray(l); s = np.asarray(s)
-    c = np.clip(c, 0, 4)
-    is_pallas = getattr(occ_fn, "is_pallas", False)
-    T = k.shape[0]
-    Tp = _bucket_tasks(T) if is_pallas else T
-    if Tp != T:
-        padw = ((0, Tp - T),) + ((0, 0),) * (k.ndim - 1)
-        k = np.pad(k, padw); l = np.pad(l, padw); s = np.pad(s, padw)
-        c = np.pad(np.asarray(c), ((0, Tp - T),) + ((0, 0),) * (c.ndim - 1),
-                   constant_values=4)
-
-    def dispatch():
-        return jf(idx.device(), jnp.asarray(k, I32.dtype),
-                  jnp.asarray(l, I32.dtype), jnp.asarray(s, I32.dtype),
-                  jnp.asarray(c, I32.dtype), occ_fn=occ_fn)
-
-    if is_pallas and obs.enabled():
-        with obs.span("kernel.fmocc", cat="kernel", tasks=T):
-            obs.count("kernel_fmocc_dispatches")
-            out = dispatch()
-            jax.block_until_ready(out)
-    else:
-        if is_pallas:
-            obs.count("kernel_fmocc_dispatches")
-        out = dispatch()
-    return tuple(np.asarray(v, np.int64)[:T] for v in out)
+    fn = forward_ext_np if which == "fwd" else backward_ext_np
+    return fn(idx, k, l, s, c, occ_np=occ_np)
 
 
 def smem1_batch(idx: FMIndex, reads: np.ndarray, lens: np.ndarray,
@@ -304,11 +261,18 @@ def smem1_batch(idx: FMIndex, reads: np.ndarray, lens: np.ndarray,
 
     Per round, ONE vectorized extension call serves every live (task, entry)
     pair — the TPU analogue of the paper's software-prefetch batching.
-    Output is bit-identical to calling ``smem1`` per task.
+    With a NumPy occ function the rounds run on the host; any other occ
+    function (jnp, or a Pallas kernel from ``kernels.fmocc.make_occ_fn``)
+    runs the whole call, every round of both phases, as one device
+    program (``_smem1_loop``).  Output is bit-identical to calling
+    ``smem1`` per task either way.
     """
     T = len(task_read)
     L = int(reads.shape[1])
     P = cap or (L + 1)
+    if occ_fn not in _NUMPY_OCC:
+        return _smem1_device(idx, reads, lens, task_read, task_x,
+                             task_min_intv, occ_fn, P)
     q = reads[task_read]                       # (T, L) uint8
     lens_t = lens[task_read].astype(np.int64)
     x = task_x.astype(np.int64)
@@ -447,7 +411,15 @@ def seed_strategy1_batch(idx: FMIndex, reads: np.ndarray, lens: np.ndarray,
                          task_read: np.ndarray, task_x: np.ndarray,
                          min_len: int, max_intv: int, *,
                          occ_fn: Callable = occ_opt_np):
-    """Lockstep-batched bwt_seed_strategy1. Returns (mem or None per task, ret)."""
+    """Lockstep-batched bwt_seed_strategy1.
+
+    Returns ``(out, has, ret)``: per task the seed (k, l, s, qbeg, qend),
+    whether there is one, and the next x.  Like ``smem1_batch``, a
+    non-NumPy occ function runs the call as one device program
+    (``_seed1_loop``)."""
+    if occ_fn not in _NUMPY_OCC:
+        return _seed1_device(idx, reads, lens, task_read, task_x, min_len,
+                             max_intv, occ_fn)
     T = len(task_read)
     L = int(reads.shape[1])
     q = reads[task_read]
@@ -495,6 +467,236 @@ def seed_strategy1_batch(idx: FMIndex, reads: np.ndarray, lens: np.ndarray,
         ik_s = np.where(upd, ok_s, ik_s)
         step += 1
     return out, has, ret
+
+
+# =====================================================================
+# Device loops: one program per smem1_batch / seed_strategy1_batch call
+# =====================================================================
+#
+# The rounds of the NumPy path above, as ``lax.while_loop``s over
+# fixed-shape (tasks, slots) state, so a call costs one dispatch and one
+# readback instead of one per round.  The occ lookups stay inside the loop
+# body (the Pallas kernel where the occ function is one).
+#
+# Entry lists keep their slots instead of being compacted: the forward
+# phase pushes from the last slot down, so slots P-n..P-1 hold the list
+# longest-first (the NumPy path's reversed list); the backward phase marks
+# the entries that survive a round in ``valid`` and leaves them where they
+# are, since compaction keeps their order anyway.  Emitted SMEMs also fill
+# the last slots first and are moved to the front, in start order, at the
+# end.
+
+def _base_at(q, pos):
+    """q[t, pos[t]] for every task, pos clipped into the read."""
+    pos = jnp.clip(pos, 0, q.shape[1] - 1)
+    return jnp.take_along_axis(q, pos[:, None], axis=1)[:, 0]
+
+
+def _take(a, col):
+    """a[t, col[t]] for every task."""
+    return jnp.take_along_axis(a, col[:, None], axis=1)[:, 0]
+
+
+def _put(a, mask, col, v):
+    """a[t, col[t]] = v[t] where mask[t]."""
+    slots = jnp.arange(a.shape[1], dtype=I32)[None, :]
+    return jnp.where(mask[:, None] & (slots == col[:, None]), v[:, None], a)
+
+
+def _first_interval(fm, q, x, lens):
+    """Bi-interval of each task's first base (``FMIndex.init_interval``),
+    and whether the task starts on a base at all."""
+    b0 = _base_at(q, x)
+    valid = (b0 <= 3) & (x < lens)
+    b = jnp.clip(b0, 0, 3)
+    size = jnp.concatenate([fm.C[1:] - fm.C[:3], (fm.N - fm.C[3])[None]])
+    zero = jnp.zeros_like(b)
+    return (valid, jnp.where(valid, fm.C[b], zero),
+            jnp.where(valid, fm.C[3 - b], zero),
+            jnp.where(valid, size[b], zero))
+
+
+def _smem1_loop(fm, q, lens, x, min_intv, *, occ_fn, P):
+    """``smem1_batch``'s rounds on the device.  q (Tp, L) uint8; lens, x,
+    min_intv (Tp,) int32.  Returns the (Tp, P) mem arrays k, l, s, qbeg,
+    qend in start order, their counts, ret, and the rounds run."""
+    q = q.astype(I32)
+    Tp = q.shape[0]
+    slots = jnp.arange(P, dtype=I32)[None, :]
+    valid0, k, l, s = _first_interval(fm, q, x, lens)
+    grid = jnp.zeros((Tp, P), I32)
+    none = jnp.zeros(Tp, I32)
+
+    # ---- forward phase: every task pushes at most one entry a step ----
+    def fwd(st):
+        step, alive, k, l, s, end, ck, cl, cs, ce, cn, rounds = st
+        i = x + step
+        in_range = alive & (i < lens)
+        ended = alive & ~in_range
+        b = _base_at(q, i)
+        amb = in_range & (b > 3)
+        alive = in_range & ~amb
+        rounds = rounds + jnp.any(alive).astype(I32)
+        ok_k, ok_l, ok_s = forward_ext_v(fm, k, l, s, jnp.clip(b, 0, 4),
+                                         occ_fn=occ_fn)
+        changed = alive & (ok_s != s)
+        push = ended | amb | changed
+        at = P - 1 - cn
+        ck = _put(ck, push, at, k); cl = _put(cl, push, at, l)
+        cs = _put(cs, push, at, s); ce = _put(ce, push, at, end)
+        cn = cn + push.astype(I32)
+        alive = alive & ~(changed & (ok_s < min_intv))
+        k = jnp.where(alive, ok_k, k); l = jnp.where(alive, ok_l, l)
+        s = jnp.where(alive, ok_s, s); end = jnp.where(alive, i + 1, end)
+        return step + 1, alive, k, l, s, end, ck, cl, cs, ce, cn, rounds
+
+    st = lax.while_loop(lambda st: jnp.any(st[1]), fwd,
+                        (jnp.int32(1), valid0, k, l, s, x + 1,
+                         grid, grid, grid, grid, none, jnp.int32(0)))
+    pk, pl, ps, pe, cn, rounds = st[6:]
+    longest = jnp.clip(P - cn, 0, P - 1)
+    ret = jnp.where(valid0 & (cn > 0), _take(pe, longest), x + 1)
+
+    # ---- backward phase: each task emits at most one SMEM a round ----
+    def bwd(st):
+        i, active, valid, pk, pl, ps, mk, ml, ms, mb, me, mn, rounds = st
+        bi = _base_at(q, i)
+        c = jnp.where(active & (i >= 0) & (bi <= 3), bi, -1)
+        cc = jnp.broadcast_to(jnp.where(c >= 0, c, 4)[:, None], (Tp, P))
+        ok_k, ok_l, ok_s = backward_ext_v(fm, pk, pl, ps, cc, occ_fn=occ_fn)
+        live = active[:, None] & valid
+        fails = live & ((c < 0)[:, None] | (ok_s < min_intv[:, None]))
+        good = live & ~fails
+        # a surviving entry is kept when its size differs from the last
+        # kept size, which is the size of the surviving entry before it
+        before = lax.cummax(jnp.where(good, slots, -1), axis=1)
+        before = jnp.pad(before[:, :-1], ((0, 0), (1, 0)),
+                         constant_values=-1)
+        keep = good & ((before < 0) | (
+            ok_s != jnp.take_along_axis(ok_s, jnp.maximum(before, 0),
+                                        axis=1)))
+        # the first failing entry is emitted when no entry before it
+        # survives and it is not contained in the last SMEM emitted
+        j = jnp.min(jnp.where(fails, slots, P), axis=1)
+        first_good = jnp.min(jnp.where(good, slots, P), axis=1)
+        last_qb = _take(mb, jnp.clip(P - mn, 0, P - 1))
+        emit = (j < first_good) & ((mn == 0) | (i + 1 < last_qb))
+        j = jnp.minimum(j, P - 1)
+        at = P - 1 - mn
+        mk = _put(mk, emit, at, _take(pk, j)); ml = _put(ml, emit, at, _take(pl, j))
+        ms = _put(ms, emit, at, _take(ps, j)); mb = _put(mb, emit, at, i + 1)
+        me = _put(me, emit, at, _take(pe, j))
+        mn = mn + emit.astype(I32)
+        zero = jnp.zeros_like(ok_k)
+        pk = jnp.where(keep, ok_k, zero); pl = jnp.where(keep, ok_l, zero)
+        ps = jnp.where(keep, ok_s, zero)
+        active = active & jnp.any(keep, axis=1) & (i >= 0)
+        return (i - 1, active, keep, pk, pl, ps, mk, ml, ms, mb, me, mn,
+                rounds + 1)
+
+    valid = valid0[:, None] & (slots >= (P - cn)[:, None])
+    st = lax.while_loop(lambda st: jnp.any(st[1]), bwd,
+                        (x - 1, valid0 & (cn > 0), valid, pk, pl, ps,
+                         grid, grid, grid, grid, grid, none, rounds))
+    mems, mn, rounds = st[6:11], st[11], st[12]
+    src = jnp.clip(P - mn[:, None] + slots, 0, P - 1)
+    head = slots < mn[:, None]
+    mems = [jnp.where(head, jnp.take_along_axis(m, src, axis=1), 0)
+            for m in mems]
+    return (*mems, mn, ret, rounds)
+
+
+def _seed1_loop(fm, q, lens, x, min_len, max_intv, *, occ_fn):
+    """``seed_strategy1_batch``'s rounds on the device.  Returns the
+    (Tp, 5) seeds (k, l, s, qbeg, qend), whether each task has one, ret,
+    and the rounds run."""
+    q = q.astype(I32)
+    valid0, k, l, s = _first_interval(fm, q, x, lens)
+
+    def body(st):
+        step, alive, k, l, s, out, has, ret, rounds = st
+        i = x + step
+        alive = alive & (i < lens)
+        b = _base_at(q, i)
+        amb = alive & (b > 3)
+        ret = jnp.where(amb, i + 1, ret)
+        alive = alive & ~amb
+        rounds = rounds + jnp.any(alive).astype(I32)
+        ok_k, ok_l, ok_s = forward_ext_v(fm, k, l, s, jnp.clip(b, 0, 4),
+                                         occ_fn=occ_fn)
+        hit = alive & (ok_s < max_intv) & (i - x >= min_len)
+        good = hit & (ok_s > 0)
+        seed = jnp.stack([ok_k, ok_l, ok_s, x, i + 1], axis=1)
+        out = jnp.where(good[:, None], seed, out)
+        ret = jnp.where(hit, i + 1, ret)
+        alive = alive & ~hit
+        k = jnp.where(alive, ok_k, k); l = jnp.where(alive, ok_l, l)
+        s = jnp.where(alive, ok_s, s)
+        return step + 1, alive, k, l, s, out, has | good, ret, rounds
+
+    st = lax.while_loop(lambda st: jnp.any(st[1]), body,
+                        (jnp.int32(1), valid0, k, l, s,
+                         jnp.zeros((q.shape[0], 5), I32),
+                         jnp.zeros_like(valid0),
+                         jnp.where(valid0, lens, x + 1), jnp.int32(0)))
+    return st[5:]
+
+
+_smem1_loop_j = jax.jit(_smem1_loop, static_argnames=("occ_fn", "P"))
+_seed1_loop_j = jax.jit(_seed1_loop, static_argnames=("occ_fn",))
+
+
+def _device_tasks(reads, lens, task_read, task_x):
+    """The tasks' reads, lengths and start positions, padded to
+    ``_bucket_tasks`` (pad tasks have length 0)."""
+    T = len(task_read)
+    Tp = _bucket_tasks(T)
+    q = np.zeros((Tp, reads.shape[1]), np.uint8)
+    q[:T] = reads[task_read]
+    lens_t = np.zeros(Tp, np.int32)
+    lens_t[:T] = lens[task_read]
+    x = np.zeros(Tp, np.int32)
+    x[:T] = task_x
+    return q, lens_t, x
+
+
+def _run_loop(loop, T, occ_fn, *args, **static):
+    """Dispatch one device loop and read its results back once.
+
+    With a Pallas occ function the dispatch and its wait are one
+    ``kernel.fmocc`` span, whose ``tasks`` is T summed over the rounds
+    the loop ran: what the per-round spans of a host loop would add up
+    to.  Returns the results cut to the T tasks, as int64."""
+    obs.count("smem_occ_dispatches")
+    pallas = getattr(occ_fn, "is_pallas", False)
+    if pallas:
+        obs.count("kernel_fmocc_dispatches")
+    span = obs.span("kernel.fmocc", cat="kernel") if pallas else obs.NULL_SPAN
+    with span as sp:
+        *out, rounds = jax.device_get(loop(*args, occ_fn=occ_fn, **static))
+        rounds = int(rounds)
+        sp.set_args(tasks=T * rounds)
+    obs.count("smem_rounds", rounds)
+    return [np.asarray(v[:T], np.int64) for v in out]
+
+
+def _smem1_device(idx, reads, lens, task_read, task_x, task_min_intv,
+                  occ_fn, P) -> SmemTaskBatch:
+    T = len(task_read)
+    q, lens_t, x = _device_tasks(reads, lens, task_read, task_x)
+    min_intv = np.ones(len(x), np.int32)
+    min_intv[:T] = np.maximum(task_min_intv, 1)
+    return SmemTaskBatch(*_run_loop(_smem1_loop_j, T, occ_fn, idx.device(),
+                                    q, lens_t, x, min_intv, P=P))
+
+
+def _seed1_device(idx, reads, lens, task_read, task_x, min_len, max_intv,
+                  occ_fn):
+    q, lens_t, x = _device_tasks(reads, lens, task_read, task_x)
+    out, has, ret = _run_loop(_seed1_loop_j, len(task_read), occ_fn,
+                              idx.device(), q, lens_t, x, np.int32(min_len),
+                              np.int32(max_intv))
+    return out, has.astype(bool), ret
 
 
 def collect_smems_batch(idx: FMIndex, reads: np.ndarray, lens: np.ndarray,

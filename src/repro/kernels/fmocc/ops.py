@@ -3,16 +3,16 @@
 The public entry points (``occ_pallas`` / ``backward_ext_pallas``) are
 plain Python wrappers that resolve ``interpret`` and call the jitted
 implementations; the kernel tests drive them.  The pipeline does not:
-the SMEM search jits its own round around ``make_occ_fn``'s callable,
-and its ``kernel.fmocc`` span and dispatch count live there
-(``core.smem._ext_round``).
+the SMEM search jits its own device loops around ``make_occ_fn``'s
+callable, and their ``kernel.fmocc`` span and dispatch count live there
+(``core.smem._run_loop``).
 
 ``interpret`` resolves from the active JAX backend when left ``None``
 (interpret on CPU, compiled on TPU/GPU — see ``kernels.config``).
 
 ``make_occ_fn`` builds the pipeline-facing occ callable for one
 (layout, qb, interpret) configuration.  The SMEM search passes occ
-functions as STATIC jit arguments (``core.smem._fwd_round_j``), so the
+functions as STATIC jit arguments (``core.smem._smem1_loop_j``), so the
 factory is cached: one stable function object per configuration, no
 retraces across calls or indexes.  The engine's occ-layout sweep
 (``kernels.engine``) times these configurations and picks one per

@@ -54,6 +54,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_args(self, **args):
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -218,6 +221,11 @@ class _Span:
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set_args(self, **args):
+        """Add args known only inside the span (a count the timed work
+        returns); they land on the trace event at exit."""
+        self._args = {**(self._args or {}), **args}
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0

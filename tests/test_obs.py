@@ -491,3 +491,42 @@ def test_compile_span_only_under_telemetry():
     assert ev["name"] == "compile" and ev["cat"] == "compile"
     assert ev["args"]["fun"] == "jit(<lambda>)"
     assert ev["dur"] == pytest.approx(snap["time_compile_s"] * 1e6)
+
+
+def test_fmocc_span_per_device_loop_carries_the_rounds_tasks(world,
+                                                             monkeypatch):
+    """One ``kernel.fmocc`` span per SMEM device loop; its ``tasks`` is the
+    sum, over the rounds the host's NumPy path runs on the same input, of
+    the call's task count: what per-round dispatch spans added up to."""
+    from repro.core import smem as sm
+    from repro.core.fmindex import occ_opt_np
+    from repro.kernels.fmocc.ops import make_occ_fn
+
+    idx, reads = world
+    lens = np.full(len(reads), reads.shape[1], np.int64)
+    opt = sm.MemOptions()
+    host_tasks = []
+    ext_round = sm._ext_round
+
+    def counted(idx_, which, k, *a, **kw):
+        host_tasks.append(len(k))
+        return ext_round(idx_, which, k, *a, **kw)
+
+    monkeypatch.setattr(sm, "_ext_round", counted)
+    want = sm.collect_smems_batch(idx, reads, lens, opt, occ_fn=occ_opt_np)
+    monkeypatch.setattr(sm, "_ext_round", ext_round)
+
+    tele, reg = obs.Telemetry(trace=True), obs.MetricsRegistry()
+    with tele.activate(reg):
+        got = sm.collect_smems_batch(
+            idx, reads, lens, opt,
+            occ_fn=make_occ_fn("eta32", 256, interpret=True))
+    assert got == want
+    snap = reg.snapshot()
+    spans = [e for e in tele.tracer.to_dict()["traceEvents"]
+             if e["name"] == "kernel.fmocc"]
+    assert len(spans) == snap["kernel_fmocc_dispatches"] \
+        == snap["smem_occ_dispatches"] < len(host_tasks)
+    assert sum(e["args"]["tasks"] for e in spans) == sum(host_tasks) > 0
+    assert snap["smem_rounds"] == len(host_tasks)
+    assert all(e["cat"] == "kernel" for e in spans)

@@ -2,11 +2,11 @@
 
 Interpret mode (every other kernel test) cannot show what the chip's
 compiler refuses: layouts, loop-carried vector types, block shapes.
-These tests lower and compile the Pallas kernels and the SMEM round that
-calls them for a ``v5e:2x2`` topology that is described, not attached,
+These tests lower and compile the Pallas kernels and the SMEM device loops
+that call them for a ``v5e:2x2`` topology that is described, not attached,
 at the widths the aligner runs: BSW at 2x150 read widths and at
-qmax=512, both occ layouts at the sweep's qb values, and one backward
-extension round on the shapes of a GRCh38 chr21-sized FM-index.
+qmax=512, both occ layouts at the sweep's qb values, and the smem1 and
+seed_strategy1 loops on the shapes of a GRCh38 chr21-sized FM-index.
 
 Nothing runs, so no result or time is checked here; ``chip_smoke.py``
 runs the same path on the chip.  The topology is described inside a
@@ -24,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.fmindex import BASE_ETA, OPT_ETA, FMArrays
-from repro.core.smem import _bwd_round_j
+from repro.core.smem import _seed1_loop_j, _smem1_loop_j
 from repro.kernels.bsw.kernel import bsw_pallas_call
 from repro.kernels.fmocc.kernel import (occ_count_packed_pallas_call,
                                         occ_count_pallas_call)
@@ -99,12 +99,24 @@ def _chr21_fmarrays(shape) -> FMArrays:
 
 @pytest.mark.parametrize("layout", ["eta32", "eta128"])
 def test_smem_round_with_compiled_occ_kernel(shape, layout):
-    """One 1024-task backward-extension round of 2x150 reads (151 SMEM
-    slots per task) on chr21-sized index shapes, with the occ lookups in
-    the compiled Pallas kernel."""
-    T, P = 1024, 151
+    """The smem1 loop over 1024 tasks of 150 bp reads (151 SMEM slots per
+    task), both phases' rounds in one program, on chr21-sized index
+    shapes, with the occ lookups in the compiled Pallas kernel."""
+    T, L = 1024, 150
     occ_fn = make_occ_fn(layout, 256, interpret=False)
-    compiled = _bwd_round_j.lower(
-        _chr21_fmarrays(shape), shape((T, P)), shape((T, P)),
-        shape((T, P)), shape((T, P)), occ_fn=occ_fn).compile()
+    col = shape((T,))
+    compiled = _smem1_loop_j.lower(
+        _chr21_fmarrays(shape), shape((T, L), jnp.uint8), col, col, col,
+        occ_fn=occ_fn, P=L + 1).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_seed_strategy1_loop_with_compiled_occ_kernel(shape):
+    """The third seeding round's loop, as the smem1 loop above."""
+    T, L = 1024, 150
+    occ_fn = make_occ_fn("eta32", 256, interpret=False)
+    col = shape((T,))
+    compiled = _seed1_loop_j.lower(
+        _chr21_fmarrays(shape), shape((T, L), jnp.uint8), col, col,
+        shape(()), shape(()), occ_fn=occ_fn).compile()
     assert "tpu_custom_call" in compiled.as_text()
